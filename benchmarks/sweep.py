@@ -37,8 +37,8 @@ offending config named instead of propagating NaN into the diagrams
 (the streaming path quarantines instead — see repro.core.stream).
 
 Every batched call auto-shards its config axis over all visible devices
-(``repro.core.xdes.simulate_batch(shard=...)``, ``shard_map`` through the
-version-robust shim in ``repro/sharding/compat.py``) — on a multi-device
+(``repro.core.xdes.simulate_batch(shard=...)``, ``jax.shard_map``
+imported through ``repro/sharding/compat.py``) — on a multi-device
 host the same entry points sweep 10-100k configurations.
 
 Every grid also has a **streaming** mode (``stream=True`` / ``--stream``,
@@ -531,7 +531,8 @@ def discipline_grid(n_scenarios: int = 200, target_cs: int = 150,
     if stream:
         out["meta"].update(chunk_size=res.chunk_size,
                            n_chunks=res.n_chunks,
-                           budget_mb=round(res.budget_mb, 1))
+                           budget_mb=round(res.budget_mb, 1),
+                           n_failures=len(res.failures))
     if verbose:
         print(f"\ndiscipline grid: {C} configs ({n_scenarios} "
               f"scenarios x {V} variants) x {res.n_steps} steps in "
@@ -1365,4 +1366,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

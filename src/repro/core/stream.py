@@ -148,8 +148,10 @@ def memory_budget_bytes(mem_mb: float | None = None) -> int:
 
     Priority: explicit ``mem_mb`` argument > ``REPRO_SWEEP_MEM_MB`` env
     var > :data:`DEVICE_MEM_FRACTION` of the accelerator's reported
-    ``bytes_limit`` (GPU/TPU) > :data:`DEFAULT_MEM_MB` (CPU hosts, where
-    jax reports no limit).
+    ``bytes_limit`` > :data:`DEFAULT_MEM_MB` on CPU hosts, where jax
+    reports no limit.  An accelerator that reports no ``bytes_limit`` is
+    an error, not the CPU default: a budget guessed for a device would
+    hide what the device holds.
     """
     if mem_mb is None:
         env = os.environ.get(ENV_MEM_MB)
@@ -157,14 +159,15 @@ def memory_budget_bytes(mem_mb: float | None = None) -> int:
             mem_mb = float(env)
     if mem_mb is not None:
         return int(float(mem_mb) * 2**20)
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit:
-            return int(DEVICE_MEM_FRACTION * limit)
-    except Exception:          # backends without memory_stats()
-        pass
-    return int(DEFAULT_MEM_MB * 2**20)
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return int(DEFAULT_MEM_MB * 2**20)
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            f"bytes_limit; pass mem_mb or set {ENV_MEM_MB}")
+    return int(DEVICE_MEM_FRACTION * limit)
 
 
 def plan_chunks(C: int, T: int, *, mem_mb: float | None = None,

@@ -352,9 +352,12 @@ def _sharded_fn(n_steps: int | None, T: int, backend: str, n_dev: int,
     spec = PartitionSpec("configs")
     rep = PartitionSpec()
 
-    # check_vma=False: the pinned JAX has no replication rule for `while`
-    # (the blocked rollout's chunk loop); replication checking adds no
-    # safety here — every output is config-partitioned, never replicated.
+    # check_vma=False: with the check on, JAX 0.9 types every loop carry
+    # and every pallas_call output by the mesh axes it varies over.  The
+    # XLA rollout passes once its constant initial carry is pcast to
+    # varying, but interpret-mode Pallas drops the axis inside its grid
+    # loop ("varying manual axes do not match").  The check adds no
+    # safety here: every output is config-partitioned, never replicated.
     if n_steps is None:
         def run_dyn(arrs, ns, tc):
             return _simulate_core(arrs, ns, T=T, backend=backend,

@@ -54,7 +54,8 @@ from jax.experimental import pallas as pl
 from repro.core.policy import CS, NCS, SPIN, oracle_update
 
 from .pallas_compat import CompilerParams, resolve_interpret
-from .ref import NO_TICKET, lock_sim_block_ref, lock_transitions_ref
+from .ref import (NO_TICKET, count_scale, lock_sim_block_ref,
+                  lock_transitions_ref)
 
 LANE = 128          # TPU lane width: thread axis is padded to this
 
@@ -79,7 +80,7 @@ def _kernel(state_ref, rem_ref, alpha_ref, cores_ref, dt_ref, budget_ref,
            + jnp.where(is_ncs, d_rate, 0.0)
            + jnp.where(budget_ref[...] > 0, burn, 0.0))
     rem_out_ref[...] = rem - dec
-    burn_out_ref[...] = jnp.sum(burn, axis=-1, keepdims=True)
+    burn_out_ref[...] = count_scale(d_rate, n_spin)
 
 
 @functools.partial(jax.jit, static_argnames=("block_configs", "interpret"))
@@ -379,6 +380,17 @@ _BLOCK_CTX_DTYPES = (jnp.int32, jnp.int32, jnp.float32, jnp.float32,
 
 _N_BLOCK_CTX = len(_BLOCK_CTX_DTYPES)
 
+#: Config rows per grid step of :func:`lock_sim_block`, and the scoped
+#: VMEM it may claim when compiled for a TPU.  Every ``(bc, 1)`` column
+#: block takes a whole ``(8, 128)`` lane tile and is double-buffered, so
+#: the 41 per-config input and 9 output columns, not the thread state,
+#: dominate the footprint.  Least limit that compiles for TPU v5e (AOT,
+#: T <= 128 lanes, 32 sub-steps): 10 MiB closed / 14 MiB open at 128 rows,
+#: 20 / 30 MiB at 256 rows — over the compiler's 16 MiB default, so the
+#: limit is set, with 2x headroom over the open-loop need.
+BLOCK_CONFIGS = 128
+BLOCK_VMEM_LIMIT = 32 * 2**20
+
 
 def _block_kernel(n_sub_steps, open_run, *refs):
     n_in = _N_THREAD + 1 + _N_CONF + _N_BLOCK_CTX \
@@ -409,7 +421,7 @@ def lock_sim_block(st, rem, wake_at, slept, spun, ctr, ticket,
                    wl_period, wl_duty, wl_burst, wl_spread, arrival,
                    arr_rate, q_cap, slo, tb, fault, flt_rate, flt_scale,
                    park_cost, *,
-                   n_sub_steps: int, block_configs: int = 256,
+                   n_sub_steps: int, block_configs: int = BLOCK_CONFIGS,
                    interpret: bool | None = None, limit=None,
                    open_state=None):
     """Pallas time-blocked rollout kernel; signature mirrors
@@ -473,7 +485,8 @@ def lock_sim_block(st, rem, wake_at, slept, spun, ctr, ticket,
         + [jax.ShapeDtypeStruct((C + pc, 1), jnp.float32)]
         + open_shapes,
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=CompilerParams(dimension_semantics=("parallel",),
+                                       vmem_limit_bytes=BLOCK_VMEM_LIMIT),
     )(*thread_in, cpu_in, *conf_in, *ctx_in, *open_in)
     nclosed = _N_THREAD + _N_CONF + 1
     res = tuple(v[:C, :T] for v in out[:_N_THREAD]) \

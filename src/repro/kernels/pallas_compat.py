@@ -1,13 +1,7 @@
-"""Version-robust aliases for the Pallas TPU API.
+"""The one import site of the Pallas TPU compiler parameters, and the
+shared backend auto-detect for every ``pallas_call`` site.
 
-The pinned JAX exposes TPU compiler parameters as
-``pltpu.TPUCompilerParams``; newer releases renamed it to
-``pltpu.CompilerParams`` (and deprecated the old name).  Every kernel
-imports :data:`CompilerParams` from here so the repo tracks either
-spelling without per-module try/except blocks.
-
-Also home of :func:`default_interpret` — the shared backend auto-detect
-for every ``pallas_call`` site: interpret mode only when no accelerator is
+:func:`default_interpret` picks interpret mode only when no accelerator is
 attached (CPU hosts, CI), compiled lowering on real GPU/TPU devices.
 """
 
@@ -16,10 +10,7 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from jax.experimental.pallas.tpu import CompilerParams
 
 
 @functools.lru_cache(maxsize=1)

@@ -94,7 +94,13 @@ def lock_sim_step_ref(tstate, rem, alpha, cores, dt, has_budget):
     rem:    (C, T) f32 remaining work (CS/NCS) or spin budget (adaptive);
     alpha, cores, dt: (C,) f32; has_budget: (C,) bool.
     Returns ``(rem', spin_burn)`` with spin_burn (C,) f32 — the CPU-seconds
-    burnt spinning this step (the paper's sync-waste metric).
+    burnt spinning this step (the paper's sync-waste metric).  Every
+    spinner burns the same ``dt * rate``, so spin_burn is that times the
+    exact spinner count: one rounding, whatever order or lane padding a
+    compiler reduces in (an f32 lane sum differs between XLA and Mosaic).
+    :func:`count_scale` keeps that one rounding when a compiler fuses the
+    product into the caller's ``spin_cpu + burn`` as an FMA (XLA:CPU does,
+    and not in the same places in both twins).
     """
     from repro.core.policy import CS, NCS, SPIN
 
@@ -110,7 +116,7 @@ def lock_sim_step_ref(tstate, rem, alpha, cores, dt, has_budget):
     dec = (jnp.where(is_cs, (dt * holder_rate)[:, None], 0.0)
            + jnp.where(is_ncs, d_rate[:, None], 0.0)
            + jnp.where(has_budget[:, None], burn, 0.0))
-    return rem - dec, jnp.sum(burn, axis=-1)
+    return rem - dec, count_scale(d_rate, n_spin)
 
 
 # --------------------------------------------------------------------------
@@ -155,6 +161,63 @@ OPEN_STATE = ("req_t", "qbuf", "hist", "qhead", "qlen", "arrived", "shed",
               "departed", "slo_viol", "lat_sum", "occ_int")
 
 
+# --------------------------------------------------------------------------
+# Mosaic-compilable spellings of three primitives the TPU kernel lowering
+# refuses (uint32 -> float32 casts, lane-axis cumsum, argmax of a bool
+# mask), each exactly equal to the primitive it replaces, and a product
+# that rounds the same under every compiler's fusion.  So the XLA
+# reference and the compiled kernel keep every simulated bit (pinned by
+# tests/test_lock_kernel_ops.py).
+# --------------------------------------------------------------------------
+def u32_to_f32(x):
+    """``x.astype(jnp.float32)`` for a uint32 ``x``, bit for bit.
+
+    The high and low 16-bit halves each convert exactly (< 2**16), the
+    scaled high half ``hi * 2**16`` is exact too, so the one f32 add rounds
+    the exact value once, to nearest even — the direct conversion."""
+    hi = (x >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (x & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(2.0 ** 16) + lo
+
+
+def lane_cumsum(mask):
+    """``jnp.cumsum(mask.astype(jnp.int32), axis=-1)`` of a bool mask.
+
+    A 0/1 upper-triangular matmul: every product is 0 or 1 and every
+    partial sum an integer <= the lane count, so the f32 accumulation is
+    exact at any matmul precision."""
+    T = mask.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    tri = (row <= col).astype(jnp.float32)
+    return jnp.dot(mask.astype(jnp.float32), tri,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def first_true(mask):
+    """Index of the first True along the last axis, keepdims; the axis
+    length where a row has none.  Equals ``jnp.argmax(mask, -1)`` on every
+    row with a True."""
+    T = mask.shape[-1]
+    idx = jax.lax.broadcasted_iota(jnp.int32, mask.shape, mask.ndim - 1)
+    return jnp.min(jnp.where(mask, idx, T), axis=-1, keepdims=True)
+
+
+def count_scale(x, n):
+    """``x * n`` for f32 ``x`` and an integer-valued f32 count ``0 <= n <
+    2**12``, rounded once, and never fused with a following add.
+
+    ``x`` splits into a head with 12 significant bits and the exact rest,
+    so both partial products are exact and only their sum rounds.  The
+    result is an add, not a multiply, so no compiler can contract it with
+    what the caller adds to it; and contracting the sum itself into an FMA
+    changes nothing, its products being exact."""
+    head = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-4096),
+        jnp.float32)
+    return head * n + (x - head) * n
+
+
 def counter_uniform(seed, tid, ctr):
     """Counter-based RNG: uniform [0,1) per (config, thread, event) from a
     splitmix-style avalanche — deterministic, stateless, replayable per
@@ -166,7 +229,7 @@ def counter_uniform(seed, tid, ctr):
     x = x ^ (x >> 15)
     x = x * jnp.uint32(0x846CA68B)
     x = x ^ (x >> 16)
-    return x.astype(jnp.float32) * jnp.float32(2.0 ** -32)
+    return u32_to_f32(x) * jnp.float32(2.0 ** -32)
 
 
 # --------------------------------------------------------------------------
@@ -392,9 +455,13 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
 
     def first_oh(mask):
         """One-hot of the lowest-tid True per row (all-False rows stay
-        all-False)."""
-        idx = jnp.argmax(mask, axis=-1, keepdims=True)
-        return (tid == idx) & jnp.any(mask, axis=-1, keepdims=True)
+        all-False: their first_true is T, which no tid equals)."""
+        return tid == first_true(mask)
+
+    def pick(c, a, b):
+        """``where(c, a, b)`` for bool ``a``/``b`` (Mosaic cannot select
+        between two bool vectors)."""
+        return (c & a) | (~c & b)
 
     def thc_of(s):
         """Algorithm 1's thc: holder + every waiter (CS/SPIN/SLEEP/WAKING),
@@ -415,7 +482,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     def park(mask, st, wake_at, permits, wake_count, slept, rem):
         """DES ``_sleep``: park, absorbing banked permits (semaphore law —
         an absorbed permit still pays the park/unpark round trip)."""
-        rank = jnp.cumsum(mask.astype(jnp.int32), axis=-1) - 1
+        rank = lane_cumsum(mask) - 1
         grant = mask & (rank < col(permits))
         n_grant = jnp.sum(grant.astype(jnp.int32), axis=-1)
         st = jnp.where(grant, P.WAKING,
@@ -459,7 +526,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     # no ordering constraint and the historical id pick is unchanged.
     wkey = jnp.where(due, ticket, NO_TICKET)
     winA_f = first_oh(due & (wkey == jnp.min(wkey, axis=-1, keepdims=True)))
-    winA = jnp.where(col(fifo_f) > 0, winA_f, first_oh(due)) & holder_free
+    winA = pick(col(fifo_f) > 0, winA_f, first_oh(due)) & holder_free
     cs_val, ctr = draw_into(winA, cs_lo, cs_hi, ctr)
     rem = jnp.where(winA, cs_val, rem)
     st = jnp.where(winA, P.CS, st)
@@ -545,7 +612,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
                                        can_handoff.astype(jnp.int32))
     quota = jnp.where(rel, quota, 0)
     sleepers = st == P.SLEEP_ST
-    rank_s = jnp.cumsum(sleepers.astype(jnp.int32), axis=-1) - 1
+    rank_s = lane_cumsum(sleepers) - 1
     sel_id = sleepers & (rank_s < col(quota))
     # FIFO rows wake the oldest ticket first (hapax head-of-queue unlock;
     # their quota is 0/1, so the single min-ticket pick covers it) — the
@@ -554,7 +621,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     sel_f = first_oh(sleepers
                      & (skey == jnp.min(skey, axis=-1, keepdims=True))) \
         & (col(quota) > 0)
-    sel = jnp.where(col(fifo_f) > 0, sel_f, sel_id)
+    sel = pick(col(fifo_f) > 0, sel_f, sel_id)
     n_sel = jnp.sum(sel.astype(jnp.int32), axis=-1)
     st = jnp.where(sel, P.WAKING, st)
     wake_at = jnp.where(sel, col(now2) + wake_eff, wake_at)
@@ -588,7 +655,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     # -- arrivals (NCS finished) ------------------------------------------
     arr = (st == P.NCS) & (rem <= REM_EPS) & active
     thc_base = thc_of(st)
-    rank_a = jnp.cumsum(arr.astype(jnp.int32), axis=-1) - 1
+    rank_a = lane_cumsum(arr) - 1
     thc_pre_i = col(thc_base) + rank_a                     # A4 per arrival
     slept = jnp.where(arr, 0, slept)                       # A3
     spun = jnp.where(arr, 0, spun)
@@ -614,7 +681,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     # FIFO rows that park (hapax) ticket their parking arrivals too — for
     # every other row the joiner set is exactly the new spinners.
     joiners = to_spinC | (sleeps & (col(fifo_f) > 0))
-    rank_t = jnp.cumsum(joiners.astype(jnp.int32), axis=-1) - 1
+    rank_t = lane_cumsum(joiners) - 1
     ticket = jnp.where(joiners, col(nticket) + rank_t, ticket)
     nticket = nticket + jnp.sum(joiners.astype(jnp.int32), axis=-1)
     # backoff rows: a new spinner starts its attempt counter at 0 and
@@ -643,7 +710,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     # bound) is counted for exactly the steps between its admission and
     # its departure.
     freem = active & (st == P.DONE) & openc
-    rank_f = jnp.cumsum(freem.astype(jnp.int32), axis=-1) - 1
+    rank_f = lane_cumsum(freem) - 1
     n_free = jnp.sum(freem.astype(jnp.int32), axis=-1)
     n_bind = jnp.minimum(qlen, n_free)
     bindm = freem & (rank_f < col(n_bind))

@@ -15,13 +15,8 @@ import jax
 
 
 def _mk(shape, axes):
-    # jax.sharding.AxisType landed after the pinned JAX; Auto is the
-    # default there anyway, so only pass axis_types when it exists.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,36 +1,10 @@
-"""Version-robust ``shard_map`` (sibling of ``repro.kernels.pallas_compat``).
-
-Newer JAX promotes ``shard_map`` to ``jax.shard_map`` with ``check_vma``
-and ``axis_names`` (the manual axes) keywords; the pinned JAX ships it as
-``jax.experimental.shard_map.shard_map`` with the older ``check_rep`` /
-``auto`` (the *non*-manual axes) spelling.  Model/train code writes the new
-API and imports :func:`shard_map` from here; on old JAX the keywords are
-translated (``axis_names`` -> ``auto`` = mesh axes minus manual ones,
-``check_vma`` -> ``check_rep``).
-"""
+"""The one import site of ``shard_map`` (sibling of
+``repro.kernels.pallas_compat``): model, train and engine code import
+:func:`shard_map` from here, with the ``check_vma`` / ``axis_names``
+keywords of ``jax.shard_map``."""
 
 from __future__ import annotations
 
-import jax
-
-_new_shard_map = getattr(jax, "shard_map", None)
-
-if _new_shard_map is not None:
-    shard_map = _new_shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None, check_rep=None, auto=None):
-        if auto is None:
-            if axis_names is not None:
-                auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            else:
-                auto = frozenset()
-        if check_rep is None:
-            check_rep = True if check_vma is None else check_vma
-        return _old_shard_map(f, mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_rep,
-                              auto=auto)
+from jax import shard_map
 
 __all__ = ["shard_map"]

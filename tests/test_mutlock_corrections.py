@@ -103,8 +103,12 @@ def test_c2_clamp_never_drops_window_below_one():
 # --------------------------------------------------------------------------
 def _run_workers(lock, n_threads, iters, cs=2e-5):
     counter = [0]
+    # all threads contend from the first acquire: started one by one on a
+    # loaded host, the first could finish its early iterations alone
+    start = threading.Barrier(n_threads)
 
     def worker():
+        start.wait()
         for _ in range(iters):
             with lock:
                 counter[0] += 1
