@@ -15,17 +15,15 @@
 | arrival    | open-loop traffic map  | benchmarks.arrival_diagram (sharded xdes) |
 | fault      | fault x lock map       | benchmarks.fault_diagram (sharded xdes) |
 | park       | park-cost x lock map   | benchmarks.park_diagram (sharded xdes) |
-| perf       | engine perf trajectory | benchmarks.perf_bench   |
 | fidelity   | dt-convergence study   | benchmarks.fidelity_study (xdes vs DES; not in --quick/--full, run on demand) |
 
 Artifacts land in reports/* (JSON plus the oracle and discipline
-phase-diagram CSV/markdown, and the measured perf trajectory —
-``BENCH_xdes.json`` at the repo root is the committed perf BASELINE,
-refreshed only by an explicit ``perf_bench --out BENCH_xdes.json``); a
-summary CSV is printed at the end.  ``--quick`` runs the batched xdes sweep, the oracle-family grid,
-the discipline/workload/arrival/fault diagrams and the perf
-microbenchmark at smoke scale (~2-3 min) — the fast signal that the
-simulation stack works end to end and hasn't slowed down.
+phase-diagram CSV/markdown); a summary CSV is printed at the end.
+``--quick`` runs the batched xdes sweep, the oracle-family grid and the
+discipline/workload/arrival/fault/park diagrams at smoke scale — the
+fast signal that the simulation stack works end to end.  Speed is
+measured on the chip by the benchmark in ``bench/`` (``BENCHMARK.json``),
+not here.
 """
 
 from __future__ import annotations
@@ -109,17 +107,6 @@ def main(argv=None) -> None:
             top = max(rows, key=lambda d: rows[d]["wins"])
             summary.append((f"park.{p}.top", top))
         print("\n" + "=" * 72)
-        print("[quick] xdes perf microbenchmark")
-        print("=" * 72)
-        from benchmarks import perf_bench
-        # reports/ output: the repo-root BENCH_xdes.json is the committed
-        # baseline the CI gate compares against — refresh it deliberately
-        # via `perf_bench --full-size --out BENCH_xdes.json`.
-        pb = perf_bench.main(["--quick",
-                              "--out", "reports/bench_xdes_quick.json"])
-        for name, x in pb["speedups"].items():
-            summary.append((f"perf.{name}", x))
-        print("\n" + "=" * 72)
         print(f"quick smoke done in {time.time()-t0:.0f}s — summary CSV")
         print("=" * 72)
         print("name,value")
@@ -128,7 +115,7 @@ def main(argv=None) -> None:
         return
 
     print("=" * 72)
-    print("[1/12] lockbench fig1 (paper Fig. 1 timelines)")
+    print("[1/11] lockbench fig1 (paper Fig. 1 timelines)")
     print("=" * 72)
     from benchmarks import lockbench
     f1 = lockbench.fig1()
@@ -140,7 +127,7 @@ def main(argv=None) -> None:
                     f1["mutable"]["makespan_slots"]))
 
     print("\n" + "=" * 72)
-    print("[2/12] lockbench fig3 (paper Fig. 3 grid, batched xdes engine)")
+    print("[2/11] lockbench fig3 (paper Fig. 3 grid, batched xdes engine)")
     print("=" * 72)
     f3 = lockbench.fig3(target_cs=400 if args.full else 200)
     for regime, data in f3.items():
@@ -151,7 +138,7 @@ def main(argv=None) -> None:
         json.dump({"fig1": f1, "fig3": f3}, f, indent=1)
 
     print("\n" + "=" * 72)
-    print("[3/12] batched xdes sweep (fig3 grid + 1000-config scenarios)")
+    print("[3/11] batched xdes sweep (fig3 grid + 1000-config scenarios)")
     print("=" * 72)
     from benchmarks import sweep
     sw = sweep.main(["--target-cs", "250" if args.full else "150"])
@@ -161,7 +148,7 @@ def main(argv=None) -> None:
         summary.append((f"sweep.scenario.{lock}.mean_ratio", round(r, 3)))
 
     print("\n" + "=" * 72)
-    print("[4/12] PHOLD on share-everything PDES (paper Fig. 4)")
+    print("[4/11] PHOLD on share-everything PDES (paper Fig. 4)")
     print("=" * 72)
     from benchmarks import phold
     ph = phold.run_phold(n_events=3000 if args.full else 1500)
@@ -173,7 +160,7 @@ def main(argv=None) -> None:
                             locks["mutable"]["speedup"]))
 
     print("\n" + "=" * 72)
-    print("[5/12] serving-window scheduler (the technique on TPU batches)")
+    print("[5/11] serving-window scheduler (the technique on TPU batches)")
     print("=" * 72)
     from benchmarks import sched_bench
     sb = sched_bench.main(["--requests", "400" if args.full else "250"])
@@ -184,7 +171,7 @@ def main(argv=None) -> None:
                         round(agg["avg_standby"], 2)))
 
     print("\n" + "=" * 72)
-    print("[6/12] oracle-family grid (paper §5 future work, batched xdes)")
+    print("[6/11] oracle-family grid (paper §5 future work, batched xdes)")
     print("=" * 72)
     from benchmarks import oracle_ablation
     oa = oracle_ablation.main(
@@ -196,7 +183,7 @@ def main(argv=None) -> None:
                         round(row["best_tuned_mean_ratio"], 3)))
 
     print("\n" + "=" * 72)
-    print("[7/12] discipline x oracle diagram (sharded batched xdes)")
+    print("[7/11] discipline x oracle diagram (sharded batched xdes)")
     print("=" * 72)
     from benchmarks import discipline_diagram
     dd = discipline_diagram.main(
@@ -207,7 +194,7 @@ def main(argv=None) -> None:
                         round(row["best_variant_mean_ratio"], 3)))
 
     print("\n" + "=" * 72)
-    print("[8/12] workload x discipline diagram (sharded batched xdes)")
+    print("[8/11] workload x discipline diagram (sharded batched xdes)")
     print("=" * 72)
     from benchmarks import workload_diagram
     wd = workload_diagram.main(
@@ -220,7 +207,7 @@ def main(argv=None) -> None:
                               3)))
 
     print("\n" + "=" * 72)
-    print("[9/12] arrival x discipline diagram (open-loop sharded xdes)")
+    print("[9/11] arrival x discipline diagram (open-loop sharded xdes)")
     print("=" * 72)
     from benchmarks import arrival_diagram
     ad = arrival_diagram.main(
@@ -234,7 +221,7 @@ def main(argv=None) -> None:
              round(cell["mean_slo_frac"], 3)))
 
     print("\n" + "=" * 72)
-    print("[10/12] fault x discipline diagram (sharded batched xdes)")
+    print("[10/11] fault x discipline diagram (sharded batched xdes)")
     print("=" * 72)
     from benchmarks import fault_diagram
     fd = fault_diagram.main(
@@ -247,7 +234,7 @@ def main(argv=None) -> None:
                         None if ret is None else round(ret, 3)))
 
     print("\n" + "=" * 72)
-    print("[11/12] park-cost x discipline diagram (sharded batched xdes)")
+    print("[11/11] park-cost x discipline diagram (sharded batched xdes)")
     print("=" * 72)
     from benchmarks import park_diagram
     pkd = park_diagram.main(
@@ -258,16 +245,6 @@ def main(argv=None) -> None:
         ret = rows["sleep"]["mean_retained_vs_unit"]
         summary.append((f"park.{p}.sleep.retained",
                         None if ret is None else round(ret, 3)))
-
-    print("\n" + "=" * 72)
-    print("[12/12] xdes perf microbenchmark (reports/bench_xdes.json)")
-    print("=" * 72)
-    from benchmarks import perf_bench
-    pb = perf_bench.main(["--full-size"] if args.full else [])
-    with open("reports/perf_bench.md", "w") as f:
-        f.write(perf_bench.summarize(pb) + "\n")
-    for name, x in pb["speedups"].items():
-        summary.append((f"perf.{name}", x))
 
     print("\n" + "=" * 72)
     print(f"benchmark suite done in {time.time()-t0:.0f}s — summary CSV")

@@ -1442,9 +1442,8 @@ def encode_configs(configs, strict: bool = True) -> dict:
 def encode_configs_legacy(configs) -> dict:
     """The per-lambda baseline implementation of :func:`encode_configs`
     (one list comprehension per column, a Python lambda + property call
-    per config per field).  Kept for the vectorized-equality tests and as
-    the perf_bench speedup baseline — new code should call
-    :func:`encode_configs`."""
+    per config per field).  Kept for the vectorized-equality tests — new
+    code should call :func:`encode_configs`."""
     import numpy as np
 
     configs = list(configs)

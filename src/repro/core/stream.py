@@ -81,6 +81,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation, annotate_function
 
 from . import policy as P
 from . import xdes
@@ -232,11 +233,12 @@ def _cell_update(wins, completed, t_end, cell_ids, *, group: int):
     """Fold one chunk into the donated win-count accumulator: throughput
     argmax per ``group``-row block, scatter-add at ``cell_ids`` (-1 ids
     mark padded blocks and contribute nothing)."""
-    thr = completed.astype(jnp.float32) / jnp.maximum(t_end, 1e-30)
-    win = jnp.argmax(thr.reshape(-1, group), axis=1)
-    ok = cell_ids >= 0
-    return wins.at[jnp.where(ok, cell_ids, 0), win].add(
-        ok.astype(jnp.int32))
+    with jax.named_scope("cell_reduce"):
+        thr = completed.astype(jnp.float32) / jnp.maximum(t_end, 1e-30)
+        win = jnp.argmax(thr.reshape(-1, group), axis=1)
+        ok = cell_ids >= 0
+        return wins.at[jnp.where(ok, cell_ids, 0), win].add(
+            ok.astype(jnp.int32))
 
 
 @dataclass
@@ -270,6 +272,12 @@ class StreamResult:
     failures: list = field(default_factory=list)
     #: Chunks restored from a checkpoint instead of recomputed.
     resumed_chunks: int = 0
+    #: Work of this call's device calls (restored chunks excluded):
+    #: ``padded_rows`` sums the copies of a last row each call added to
+    #: reach its compiled shape, ``row_steps`` every row a call simulated,
+    #: padding included, times the steps it executed.
+    padded_rows: int = 0
+    row_steps: int = 0
     #: Open-loop outputs (``None`` on closed sweeps): the (C, LAT_NBINS)
     #: latency histogram and the (C,) request counters / accumulators —
     #: same semantics as :class:`repro.core.xdes.BatchResult`.
@@ -362,16 +370,29 @@ def _is_oom(e: BaseException) -> bool:
 
 def _run_chunk_resilient(part, n: int, horizon, T, backend, block_steps,
                          target_cs, shard, open_loop, quantum: int,
-                         chunk: int, group: int, verbose: bool = False):
+                         chunk: int, group: int, verbose: bool = False,
+                         index: int = 0):
     """Run one chunk with halving backoff: an allocation failure splits
     the chunk into two group-aligned halves and retries each, recursively
     down to one reduction group / shard quantum.  Returns the summary
-    dict trimmed to the ``n`` real rows."""
+    dict trimmed to the ``n`` real rows, the padding rows and the row
+    steps of the device calls that completed (see :class:`StreamResult`).
+
+    Each device call is one ``stream.chunk`` span (chunk ``index``, real
+    and padding rows, horizon) holding ``stream.dispatch`` (enqueue, and
+    any compile or cache load) and ``stream.wait`` (the readback)."""
     pad_to = min(chunk, quantum * xdes._pad_quantum(-(-n // quantum)))
     try:
-        res = _run_chunk(_pad_rows(part, pad_to), horizon, T, backend,
-                         block_steps, target_cs, shard, open_loop)
-        return {k: np.asarray(v)[:n] for k, v in res.items()}
+        with TraceAnnotation("stream.chunk", index=index, rows=n,
+                             padded_rows=pad_to - n, horizon=int(horizon)):
+            with TraceAnnotation("stream.dispatch"):
+                res = _run_chunk(_pad_rows(part, pad_to), horizon, T,
+                                 backend, block_steps, target_cs, shard,
+                                 open_loop)
+            with TraceAnnotation("stream.wait"):
+                res = {k: np.asarray(v) for k, v in res.items()}
+        row_steps = int(res["steps_run"].astype(np.int64).sum())
+        return {k: v[:n] for k, v in res.items()}, pad_to - n, row_steps
     except Exception as e:                      # noqa: BLE001 (filtered)
         if not _is_oom(e) or n <= quantum:
             raise
@@ -383,15 +404,14 @@ def _run_chunk_resilient(part, n: int, horizon, T, backend, block_steps,
             f"sweep chunk of {n} configs failed with an allocation error; "
             f"retrying with halved chunks ({mid} + {n - mid})",
             stacklevel=2)
-        halves = []
-        for lo, hi in ((0, mid), (mid, n)):
-            sub = {k: v[lo:hi] for k, v in part.items()}
-            halves.append(_run_chunk_resilient(
-                sub, hi - lo, horizon, T, backend, block_steps, target_cs,
-                shard, open_loop, quantum, max(quantum, pad_to // 2),
-                group, verbose))
-        return {k: np.concatenate([h[k] for h in halves])
-                for k in halves[0]}
+        halves = [_run_chunk_resilient(
+            {k: v[lo:hi] for k, v in part.items()}, hi - lo, horizon, T,
+            backend, block_steps, target_cs, shard, open_loop, quantum,
+            max(quantum, pad_to // 2), group, verbose, index)
+            for lo, hi in ((0, mid), (mid, n))]
+        return ({k: np.concatenate([h[0][k] for h in halves])
+                 for k in halves[0][0]},
+                sum(h[1] for h in halves), sum(h[2] for h in halves))
 
 
 #: Float summary columns scanned for engine non-finites (intentional NaN
@@ -457,6 +477,8 @@ def _plan_fingerprint(arrs, *, chunk, T, n_steps, target_cs, backend,
     return np.frombuffer(h.digest(), np.uint8).copy()
 
 
+# stacklevel=3 in its warnings: the caller, past the span's wrapper
+@functools.partial(annotate_function, name="stream.sweep")
 def sweep_stream(configs, *, target_cs: int = 300,
                  n_steps: int | None = None, dt=None, backend: str = "ref",
                  block_steps: int | None = None, shard: bool | None = None,
@@ -492,129 +514,139 @@ def sweep_stream(configs, *, target_cs: int = 300,
     non-finite summaries are quarantined into ``StreamResult.failures``
     (and ``failures_path`` when given) with sanitized rows feeding the
     win-count reduction.
+
+    Every stage runs under a host span of the profiler (``stream.sweep``
+    around the call; ``stream.encode``, ``stream.plan``, one
+    ``stream.chunk`` per device call, ``stream.quarantine``,
+    ``stream.reduce``, ``stream.checkpoint``), and the result counts the
+    padding rows the device calls ran (``padded_rows``, ``row_steps``).
     """
-    cols = configs if isinstance(configs, dict) else \
-        P.config_columns(configs)
-    arrs = P.encode_columns(cols, validate=isinstance(configs, dict),
-                            strict=strict)
-    C = arrs["policy"].shape[0]
-    open_loop = bool((np.asarray(arrs["arrival"]) != P.AR_CLOSED).any())
-    if reduce is not None:
-        if C % reduce.group:
-            raise ValueError(f"C={C} not a multiple of reduce.group="
-                             f"{reduce.group}")
-        if reduce.cell_ids.shape != (C // reduce.group,):
-            raise ValueError("cell_ids must have one entry per group")
+    with TraceAnnotation("stream.encode"):
+        cols = configs if isinstance(configs, dict) else \
+            P.config_columns(configs)
+        arrs = P.encode_columns(cols, validate=isinstance(configs, dict),
+                                strict=strict)
+        C = arrs["policy"].shape[0]
+        open_loop = bool((np.asarray(arrs["arrival"]) != P.AR_CLOSED).any())
+        if reduce is not None:
+            if C % reduce.group:
+                raise ValueError(f"C={C} not a multiple of reduce.group="
+                                 f"{reduce.group}")
+            if reduce.cell_ids.shape != (C // reduce.group,):
+                raise ValueError("cell_ids must have one entry per group")
 
-    auto_dt, steps_arr = xdes.plan_schedule_columns(cols, target_cs)
-    dt = auto_dt if dt is None else np.broadcast_to(
-        np.asarray(dt, np.float32), (C,)).copy()
-    if n_steps is None:
-        if int(steps_arr.max()) > xdes.MAX_STEPS and not bucket_steps:
-            over = int((steps_arr > xdes.MAX_STEPS).sum())
-            warnings.warn(
-                f"step cap {xdes.MAX_STEPS} truncates {over}/{C} configs "
-                f"below target_cs={target_cs} (see plan_schedule); "
-                f"bucket_steps=True keeps fast cells fully sampled.",
-                stacklevel=2)
-        n_steps = min(int(steps_arr.max()), xdes.MAX_STEPS)
-        if early_exit is None:
-            early_exit = True
-    elif early_exit is None:
-        early_exit = False
-    arrs["dt"] = np.asarray(dt, np.float32)
+    with TraceAnnotation("stream.plan"):
+        auto_dt, steps_arr = xdes.plan_schedule_columns(cols, target_cs)
+        dt = auto_dt if dt is None else np.broadcast_to(
+            np.asarray(dt, np.float32), (C,)).copy()
+        if n_steps is None:
+            if int(steps_arr.max()) > xdes.MAX_STEPS and not bucket_steps:
+                over = int((steps_arr > xdes.MAX_STEPS).sum())
+                warnings.warn(
+                    f"step cap {xdes.MAX_STEPS} truncates {over}/{C} configs "
+                    f"below target_cs={target_cs} (see plan_schedule); "
+                    f"bucket_steps=True keeps fast cells fully sampled.",
+                    stacklevel=3)
+            n_steps = min(int(steps_arr.max()), xdes.MAX_STEPS)
+            if early_exit is None:
+                early_exit = True
+        elif early_exit is None:
+            early_exit = False
+        arrs["dt"] = np.asarray(dt, np.float32)
 
-    T = max_threads or int(arrs["threads"].max())
-    if T < int(arrs["threads"].max()):
-        raise ValueError("max_threads smaller than widest config")
-    if shard is None:
-        shard = len(jax.devices()) > 1
-    n_dev = len(jax.devices()) if shard else 1
-    if block_steps is None:
-        block_steps = xdes.DEFAULT_BLOCK_STEPS
-    tc = int(target_cs) if early_exit else 0
+        T = max_threads or int(arrs["threads"].max())
+        if T < int(arrs["threads"].max()):
+            raise ValueError("max_threads smaller than widest config")
+        if shard is None:
+            shard = len(jax.devices()) > 1
+        n_dev = len(jax.devices()) if shard else 1
+        if block_steps is None:
+            block_steps = xdes.DEFAULT_BLOCK_STEPS
+        tc = int(target_cs) if early_exit else 0
 
-    group = reduce.group if reduce is not None else 1
-    quantum = (group * n_dev) // math.gcd(group, n_dev)
-    if chunk is None:
-        chunk = plan_chunks(C, T, mem_mb=mem_mb, quantum=quantum,
-                            open_loop=open_loop)
-    elif chunk % quantum:
-        raise ValueError(f"chunk={chunk} not a multiple of the "
-                         f"group/device quantum {quantum}")
-    bpc = bytes_per_config(T, open_loop=open_loop)
-    budget_mb = memory_budget_bytes(mem_mb) / 2**20
+        group = reduce.group if reduce is not None else 1
+        quantum = (group * n_dev) // math.gcd(group, n_dev)
+        if chunk is None:
+            chunk = plan_chunks(C, T, mem_mb=mem_mb, quantum=quantum,
+                                open_loop=open_loop)
+        elif chunk % quantum:
+            raise ValueError(f"chunk={chunk} not a multiple of the "
+                             f"group/device quantum {quantum}")
+        bpc = bytes_per_config(T, open_loop=open_loop)
+        budget_mb = memory_budget_bytes(mem_mb) / 2**20
 
-    out = {f: np.empty(C, np.float32 if f in ("spin_cpu", "t_end")
-                       else np.int32) for f in SUMMARY_FIELDS}
-    if open_loop:
-        for f in OPEN_SUMMARY_FIELDS:
-            out[f] = np.empty(C, np.int32 if f in _OPEN_INT_FIELDS
-                              else np.float32)
-        out["lat_hist"] = np.empty((C, P.LAT_NBINS), np.int32)
-    wins = (jnp.zeros((reduce.n_cells, group), jnp.int32)
-            if reduce is not None else None)
-    # Per-chunk on-device cell accumulation needs every group's rows in
-    # one call: that holds in row order, but bucketing regroups rows by
-    # horizon — there the accumulator folds once at the end instead.
-    chunk_reduce = reduce is not None and not bucket_steps
+        out = {f: np.empty(C, np.float32 if f in ("spin_cpu", "t_end")
+                           else np.int32) for f in SUMMARY_FIELDS}
+        if open_loop:
+            for f in OPEN_SUMMARY_FIELDS:
+                out[f] = np.empty(C, np.int32 if f in _OPEN_INT_FIELDS
+                                  else np.float32)
+            out["lat_hist"] = np.empty((C, P.LAT_NBINS), np.int32)
+        wins = (jnp.zeros((reduce.n_cells, group), jnp.int32)
+                if reduce is not None else None)
+        # Per-chunk on-device cell accumulation needs every group's rows in
+        # one call: that holds in row order, but bucketing regroups rows by
+        # horizon — there the accumulator folds once at the end instead.
+        chunk_reduce = reduce is not None and not bucket_steps
 
-    if bucket_steps:
-        buckets = xdes.plan_buckets(steps_arr)
-        plans = [(idx, min(int(steps_arr[idx].max()), xdes.MAX_STEPS))
-                 for idx in buckets]
-    else:
-        plans = [(None, int(n_steps))]
+        if bucket_steps:
+            buckets = xdes.plan_buckets(steps_arr)
+            plans = [(idx, min(int(steps_arr[idx].max()), xdes.MAX_STEPS))
+                     for idx in buckets]
+        else:
+            plans = [(None, int(n_steps))]
 
-    # deterministic flat chunk schedule: the unit of checkpoint/resume
-    chunk_plans = []
-    for idx, horizon in plans:
-        rows = C if idx is None else len(idx)
-        for lo in range(0, rows, chunk):
-            hi = min(lo + chunk, rows)
-            chunk_plans.append((idx, lo, hi, horizon))
+        # deterministic flat chunk schedule: the unit of checkpoint/resume
+        chunk_plans = []
+        for idx, horizon in plans:
+            rows = C if idx is None else len(idx)
+            for lo in range(0, rows, chunk):
+                hi = min(lo + chunk, rows)
+                chunk_plans.append((idx, lo, hi, horizon))
 
     failures: list = []
     mgr = None
     cursor = 0                     # chunks already committed (checkpoint)
     if checkpoint_dir is not None:
-        from repro.checkpoint.manager import CheckpointManager
-        mgr = CheckpointManager(checkpoint_dir, keep_last=2,
-                                async_save=False)
-        fp = _plan_fingerprint(
-            arrs, chunk=chunk, T=T, n_steps=int(n_steps),
-            target_cs=tc, backend=backend, bucket_steps=bucket_steps,
-            shard=shard, group=group)
-        template = {"out": {k: np.zeros_like(v) for k, v in out.items()},
-                    "wins": (np.zeros((reduce.n_cells, group), np.int32)
-                             if reduce is not None
-                             else np.zeros((1,), np.int32)),
-                    "cursor": np.zeros((), np.int64),
-                    "fingerprint": np.zeros_like(fp),
-                    "failures_json": np.zeros((), np.uint32)}
-        if resume:
-            step, tree = mgr.restore(template)
-            if tree is not None:
-                if not np.array_equal(np.asarray(tree["fingerprint"]), fp):
-                    raise ValueError(
-                        f"checkpoint in {checkpoint_dir!r} was written by "
-                        f"a different sweep plan; refusing to resume")
-                cursor = int(tree["cursor"])
-                for k in out:
-                    out[k][...] = np.asarray(tree["out"][k])
-                if reduce is not None:
-                    wins = jnp.asarray(tree["wins"])
-                nfail = int(tree["failures_json"])
-                if nfail and failures_path and os.path.exists(
-                        failures_path):
-                    with open(failures_path) as f:
-                        failures = json.load(f)["failures"][:nfail]
-                if verbose:
-                    print(f"  stream resume: {cursor}/{len(chunk_plans)} "
-                          f"chunks restored from {checkpoint_dir}")
+        with TraceAnnotation("stream.checkpoint"):
+            from repro.checkpoint.manager import CheckpointManager
+            mgr = CheckpointManager(checkpoint_dir, keep_last=2,
+                                    async_save=False)
+            fp = _plan_fingerprint(
+                arrs, chunk=chunk, T=T, n_steps=int(n_steps),
+                target_cs=tc, backend=backend, bucket_steps=bucket_steps,
+                shard=shard, group=group)
+            template = {"out": {k: np.zeros_like(v) for k, v in out.items()},
+                        "wins": (np.zeros((reduce.n_cells, group), np.int32)
+                                 if reduce is not None
+                                 else np.zeros((1,), np.int32)),
+                        "cursor": np.zeros((), np.int64),
+                        "fingerprint": np.zeros_like(fp),
+                        "failures_json": np.zeros((), np.uint32)}
+            if resume:
+                step, tree = mgr.restore(template)
+                if tree is not None:
+                    if not np.array_equal(np.asarray(tree["fingerprint"]), fp):
+                        raise ValueError(
+                            f"checkpoint in {checkpoint_dir!r} was written by "
+                            f"a different sweep plan; refusing to resume")
+                    cursor = int(tree["cursor"])
+                    for k in out:
+                        out[k][...] = np.asarray(tree["out"][k])
+                    if reduce is not None:
+                        wins = jnp.asarray(tree["wins"])
+                    nfail = int(tree["failures_json"])
+                    if nfail and failures_path and os.path.exists(
+                            failures_path):
+                        with open(failures_path) as f:
+                            failures = json.load(f)["failures"][:nfail]
+                    if verbose:
+                        print(f"  stream resume: {cursor}/{len(chunk_plans)} "
+                              f"chunks restored from {checkpoint_dir}")
 
     n_chunks = 0
     run_steps = 0
+    padded_rows = row_steps = 0
     for ci, (idx, lo, hi, horizon) in enumerate(chunk_plans):
         n_chunks += 1
         run_steps = max(run_steps, horizon)
@@ -626,49 +658,58 @@ def sweep_stream(configs, *, target_cs: int = 300,
         n = hi - lo
         # _run_chunk_resilient pads the tail chunk onto the quantized
         # shape ladder (executable reuse) and halves on OOM
-        res = _run_chunk_resilient(part, n, horizon, T, backend,
-                                   int(block_steps), tc, shard, open_loop,
-                                   quantum, chunk, group, verbose)
+        res, padded, steps = _run_chunk_resilient(
+            part, n, horizon, T, backend, int(block_steps), tc, shard,
+            open_loop, quantum, chunk, group, verbose, index=ci)
+        padded_rows += padded
+        row_steps += steps
         for f in SUMMARY_FIELDS:
             out[f][sel] = res[f]
         if open_loop:
             for f in OPEN_SUMMARY_FIELDS:
                 out[f][sel] = res[f]
             out["lat_hist"][sel] = res["lat_hist"]
-        bad = _quarantine(res, cols, gidx, failures)
+        with TraceAnnotation("stream.quarantine"):
+            bad = _quarantine(res, cols, gidx, failures)
         if chunk_reduce:
-            completed = np.where(bad, 0, res["completed"])
-            t_end = np.where(bad, 1.0, res["t_end"]).astype(np.float32)
-            cid = reduce.cell_ids[lo // group:hi // group]
-            wins = _cell_update(wins, jnp.asarray(completed),
-                                jnp.asarray(t_end), jnp.asarray(cid),
-                                group=group)
+            with TraceAnnotation("stream.reduce"):
+                completed = np.where(bad, 0, res["completed"])
+                t_end = np.where(bad, 1.0, res["t_end"]).astype(np.float32)
+                cid = reduce.cell_ids[lo // group:hi // group]
+                wins = _cell_update(wins, jnp.asarray(completed),
+                                    jnp.asarray(t_end), jnp.asarray(cid),
+                                    group=group)
         if verbose:
             print(f"  stream chunk {ci + 1}/{len(chunk_plans)}: {n} "
                   f"configs x {horizon} steps"
                   + (f" [{int(bad.sum())} quarantined]" if bad.any()
                      else ""))
         if mgr is not None:
-            if failures and failures_path:
-                _write_failures(failures_path, C, failures)
-            mgr.save(ci + 1, {
-                "out": out,
-                "wins": (np.asarray(wins) if wins is not None
-                         else np.zeros((1,), np.int32)),
-                "cursor": np.int64(ci + 1),
-                "fingerprint": fp,
-                "failures_json": np.uint32(len(failures))})
+            with TraceAnnotation("stream.checkpoint"):
+                if failures and failures_path:
+                    _write_failures(failures_path, C, failures)
+                mgr.save(ci + 1, {
+                    "out": out,
+                    "wins": (np.asarray(wins) if wins is not None
+                             else np.zeros((1,), np.int32)),
+                    "cursor": np.int64(ci + 1),
+                    "fingerprint": fp,
+                    "failures_json": np.uint32(len(failures))})
     if reduce is not None and not chunk_reduce:
-        badf = np.zeros(C, bool)
-        for f in _FINITE_FIELDS:
-            if f in out:
-                badf |= ~np.isfinite(np.asarray(out[f], np.float64))
-        wins = _cell_update(
-            jnp.zeros((reduce.n_cells, group), jnp.int32),
-            jnp.asarray(np.where(badf, 0, out["completed"])),
-            jnp.asarray(np.where(badf, 1.0,
-                                 out["t_end"]).astype(np.float32)),
-            jnp.asarray(reduce.cell_ids), group=group)
+        with TraceAnnotation("stream.reduce"):
+            badf = np.zeros(C, bool)
+            for f in _FINITE_FIELDS:
+                if f in out:
+                    badf |= ~np.isfinite(np.asarray(out[f], np.float64))
+            wins = _cell_update(
+                jnp.zeros((reduce.n_cells, group), jnp.int32),
+                jnp.asarray(np.where(badf, 0, out["completed"])),
+                jnp.asarray(np.where(badf, 1.0,
+                                     out["t_end"]).astype(np.float32)),
+                jnp.asarray(reduce.cell_ids), group=group)
+    if wins is not None:
+        with TraceAnnotation("stream.wait"):
+            wins = np.asarray(wins)
 
     if failures and failures_path:
         _write_failures(failures_path, C, failures)
@@ -678,7 +719,7 @@ def sweep_stream(configs, *, target_cs: int = 300,
             f"non-finite summaries"
             + (f" (report: {failures_path})" if failures_path else "")
             + "; their rows kept raw values but were excluded from the "
-            f"win-count reduction", stacklevel=2)
+            f"win-count reduction", stacklevel=3)
 
     return StreamResult(
         n_configs=C, n_steps=run_steps, backend=backend,
@@ -688,8 +729,9 @@ def sweep_stream(configs, *, target_cs: int = 300,
         steps_run=out["steps_run"], fairness=out["fairness"],
         chunk_size=int(chunk), n_chunks=n_chunks,
         budget_mb=float(budget_mb), bytes_per_config=bpc,
-        wins=None if wins is None else np.asarray(wins),
-        failures=failures, resumed_chunks=min(cursor, len(chunk_plans)),
+        wins=wins, failures=failures,
+        resumed_chunks=min(cursor, len(chunk_plans)),
+        padded_rows=padded_rows, row_steps=row_steps,
         lat_hist=out.get("lat_hist"), arrived=out.get("arrived"),
         shed=out.get("shed"), departed=out.get("departed"),
         slo_viol=out.get("slo_viol"), lat_sum=out.get("lat_sum"),

@@ -247,11 +247,17 @@ def _simulate_core(arrs, n_steps, T: int, backend: str = "ref",
     traced.  ``rollout="scan"``: the legacy per-step ``lax.scan`` (two
     kernel dispatches per step, static ``n_steps``, no early exit) — the
     parity reference.
+
+    The device program's stages carry ``jax.named_scope`` names, which
+    its operations keep in their metadata for a profile to read:
+    ``rollout.init``, ``rollout.block`` (the kernel body's own stage
+    scopes inside), ``rollout.exit_vote`` and ``rollout.out``.
     """
     C = arrs["policy"].shape[0]
-    budget_f = P.discipline_flags(arrs["policy"])[2]
-    has_budget = budget_f > 0
-    state0 = _init_state(arrs, T, open_loop)
+    with jax.named_scope("rollout.init"):
+        budget_f = P.discipline_flags(arrs["policy"])[2]
+        has_budget = budget_f > 0
+        state0 = _init_state(arrs, T, open_loop)
     prm = tuple(arrs[f] for f in _PRM_FIELDS)
     if early_exit is None:
         early_exit = isinstance(target_cs, int) and target_cs > 0
@@ -276,7 +282,8 @@ def _simulate_core(arrs, n_steps, T: int, backend: str = "ref",
             return (*new, spin_cpu + burn, *onew), None
 
         final, _ = jax.lax.scan(body, state0, jnp.arange(int(n_steps)))
-        return _out_dict(final, int(n_steps), arrs, keep_per_thread)
+        with jax.named_scope("rollout.out"):
+            return _out_dict(final, int(n_steps), arrs, keep_per_thread)
 
     if rollout != "blocked":
         raise ValueError(f"unknown rollout {rollout!r} (blocked|scan)")
@@ -287,12 +294,14 @@ def _simulate_core(arrs, n_steps, T: int, backend: str = "ref",
     n_blocks = (limit + (B - 1)) // B
     tc = jnp.asarray(target_cs, jnp.int32)
 
+    @jax.named_scope("rollout.block")
     def run_block(state, step0):
         ostate = tuple(state[17:]) if open_loop else None
         return block(*state[:17], jnp.asarray(step0, jnp.int32),
                      arrs["alpha"], arrs["cores"], has_budget, *prm,
                      n_sub_steps=B, limit=limit, open_state=ostate)
 
+    @jax.named_scope("rollout.exit_vote")
     def all_done(completed):
         if not early_exit:
             return jnp.bool_(False)
@@ -311,8 +320,9 @@ def _simulate_core(arrs, n_steps, T: int, backend: str = "ref",
 
     *state, nblk, done = jax.lax.while_loop(
         cond, body, (*state0, jnp.int32(0), jnp.bool_(False)))
-    executed = jnp.minimum(nblk * B, limit)
-    return _out_dict(tuple(state), executed, arrs, keep_per_thread)
+    with jax.named_scope("rollout.out"):
+        executed = jnp.minimum(nblk * B, limit)
+        return _out_dict(tuple(state), executed, arrs, keep_per_thread)
 
 
 #: Fully-static jit entry (legacy + scan path): one executable per

@@ -81,6 +81,7 @@ def rmsnorm_ref(x, w, eps: float = 1e-6):
             * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
+@jax.named_scope("gps_advance")
 def lock_sim_step_ref(tstate, rem, alpha, cores, dt, has_budget):
     """One generalized-processor-sharing advance of the batched lock sim.
 
@@ -302,6 +303,7 @@ def workload_init_rem(seed, tid, ctr0, ncs_lo, ncs_hi, workload, wl_period,
     return rem0 + phase_u * arrival_phase * (0.5 * (ncs_lo + ncs_hi))
 
 
+@jax.named_scope("fault_rewind")
 def fault_rewind(st, rem, alpha, cores, dt, now_start, seed, fault,
                  flt_rate, flt_scale):
     """Fault-row progress theft for one timestep (FAULT_ROWS dispatch).
@@ -426,32 +428,34 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     # the ring buffer into the bound thread's ``req_t`` slot.
     open_run = open_state is not None
     if open_run:
-        (req_t, qbuf, hist, qhead, qlen, arrived, shed, departed,
-         slo_viol, lat_sum, occ_int) = open_state
-        Q = qbuf.shape[1]
-        NB = hist.shape[1]
-        openc = col(arrival != P.AR_CLOSED)
-        zero_u = jnp.zeros_like(seed)
-        ar_phase = counter_uniform(seed ^ jnp.uint32(P.AR_PHASE_SALT),
-                                   zero_u, jnp.uint32(0))
-        gate_on = 1.0 - P.workload_off_gate(now2, ar_phase, wl_period,
-                                            wl_duty)
-        rate = P.arrival_rate_at(arrival, arr_rate, gate_on, wl_burst)
-        # Bernoulli-rounded count: floor(rate·dt) plus a trial on the
-        # fractional part — the expected count is exactly rate·dt, so the
-        # admitted load is dt-independent (closed rows: rate 0, count 0).
-        m = rate * dt
-        mf = jnp.floor(m)
-        u_arr = counter_uniform(seed ^ jnp.uint32(P.AR_SALT), zero_u,
-                                stepu)
-        n_arr = (mf + (u_arr < (m - mf))).astype(jnp.int32)
-        n_adm = jnp.minimum(n_arr, q_cap - qlen)   # bounded queue: shed
-        qi = jnp.arange(Q, dtype=jnp.int32)[None, :]
-        wr = ((qi - col(qhead + qlen)) % Q) < col(n_adm)
-        qbuf = jnp.where(wr, col(now2), qbuf)
-        qlen = qlen + n_adm
-        arrived = arrived + n_arr
-        shed = shed + (n_arr - n_adm)
+        with jax.named_scope("open_admit"):
+            (req_t, qbuf, hist, qhead, qlen, arrived, shed, departed,
+             slo_viol, lat_sum, occ_int) = open_state
+            Q = qbuf.shape[1]
+            NB = hist.shape[1]
+            openc = col(arrival != P.AR_CLOSED)
+            zero_u = jnp.zeros_like(seed)
+            ar_phase = counter_uniform(seed ^ jnp.uint32(P.AR_PHASE_SALT),
+                                       zero_u, jnp.uint32(0))
+            gate_on = 1.0 - P.workload_off_gate(now2, ar_phase, wl_period,
+                                                wl_duty)
+            rate = P.arrival_rate_at(arrival, arr_rate, gate_on, wl_burst)
+            # Bernoulli-rounded count: floor(rate·dt) plus a trial on the
+            # fractional part — the expected count is exactly rate·dt, so
+            # the admitted load is dt-independent (closed rows: rate 0,
+            # count 0).
+            m = rate * dt
+            mf = jnp.floor(m)
+            u_arr = counter_uniform(seed ^ jnp.uint32(P.AR_SALT), zero_u,
+                                    stepu)
+            n_arr = (mf + (u_arr < (m - mf))).astype(jnp.int32)
+            n_adm = jnp.minimum(n_arr, q_cap - qlen)   # bounded queue: shed
+            qi = jnp.arange(Q, dtype=jnp.int32)[None, :]
+            wr = ((qi - col(qhead + qlen)) % Q) < col(n_adm)
+            qbuf = jnp.where(wr, col(now2), qbuf)
+            qlen = qlen + n_adm
+            arrived = arrived + n_arr
+            shed = shed + (n_arr - n_adm)
 
     def first_oh(mask):
         """One-hot of the lowest-tid True per row (all-False rows stay
@@ -491,6 +495,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
         return (st, wake_at, permits - n_grant, wake_count + n_grant,
                 jnp.where(mask, 1, slept), jnp.where(mask, inf, rem))
 
+    @jax.named_scope("oracle")
     def oracle_acquire(happened, winner_oh, thc, sws, cnt, ewma, wuc):
         """A12-A33 at an acquisition: oracle family dispatch, A16-A17
         clamp, C1/C2 correction — windowed disciplines only."""
@@ -513,120 +518,128 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
                 jnp.where(do, ewma2, ewma), jnp.where(do, wuc + corr, wuc))
 
     # -- spin-budget exhaustion -> sleep (DES stage order) -----------------
-    exhausted = (st == P.SPIN) & (col(budget_f) > 0) & (rem <= REM_EPS)
-    st, wake_at, permits, wake_count, slept, rem = park(
-        exhausted, st, wake_at, permits, wake_count, slept, rem)
+    with jax.named_scope("spin_exhaust"):
+        exhausted = (st == P.SPIN) & (col(budget_f) > 0) & (rem <= REM_EPS)
+        st, wake_at, permits, wake_count, slept, rem = park(
+            exhausted, st, wake_at, permits, wake_count, slept, rem)
 
     # -- wake completions --------------------------------------------------
-    due = (st == P.WAKING) & (wake_at <= col(now2 + teps))
-    holder_free = ~jnp.any(st == P.CS, axis=-1, keepdims=True)
-    # FIFO rows that park (hapax) keep tickets through SLEEP/WAKING, and a
-    # wake completion grants the oldest ticket, not the lowest tid.  For
-    # every other row (and the never-parking fifo row) due threads carry
-    # no ordering constraint and the historical id pick is unchanged.
-    wkey = jnp.where(due, ticket, NO_TICKET)
-    winA_f = first_oh(due & (wkey == jnp.min(wkey, axis=-1, keepdims=True)))
-    winA = pick(col(fifo_f) > 0, winA_f, first_oh(due)) & holder_free
-    cs_val, ctr = draw_into(winA, cs_lo, cs_hi, ctr)
-    rem = jnp.where(winA, cs_val, rem)
-    st = jnp.where(winA, P.CS, st)
-    # the sleep->spin transition's payoff: a woken thread that finds the
-    # lock free acquired "slept and not spun" -> EvalSWS doubles the window
-    sws, cnt, ewma, wuc = oracle_acquire(jnp.any(winA, axis=-1), winA,
-                                         thc_of(st), sws, cnt, ewma, wuc)
-    losers = due & ~winA
-    to_spin = losers & (col(w2s_f) > 0)    # woken into the spinning window
-    st = jnp.where(to_spin, P.SPIN, st)
-    spun = jnp.where(to_spin, 1, spun)
-    # fissile (budget_spin + wake_to_spin) re-arms a fresh bounded budget;
-    # the mutable row's window spinners keep the unbounded inf sentinel
-    rem = jnp.where(to_spin,
-                    jnp.where(col(budget_f) > 0, budget_eff(sws), inf),
-                    rem)
-    to_park = losers & (col(repark_f) > 0)     # barged: park again
-    st, wake_at, permits, wake_count, slept, rem = park(
-        to_park, st, wake_at, permits, wake_count, slept, rem)
+    with jax.named_scope("wake"):
+        due = (st == P.WAKING) & (wake_at <= col(now2 + teps))
+        holder_free = ~jnp.any(st == P.CS, axis=-1, keepdims=True)
+        # FIFO rows that park (hapax) keep tickets through SLEEP/WAKING, and a
+        # wake completion grants the oldest ticket, not the lowest tid.  For
+        # every other row (and the never-parking fifo row) due threads carry
+        # no ordering constraint and the historical id pick is unchanged.
+        wkey = jnp.where(due, ticket, NO_TICKET)
+        winA_f = first_oh(due & (wkey == jnp.min(wkey, axis=-1,
+                                                 keepdims=True)))
+        winA = pick(col(fifo_f) > 0, winA_f, first_oh(due)) & holder_free
+        cs_val, ctr = draw_into(winA, cs_lo, cs_hi, ctr)
+        rem = jnp.where(winA, cs_val, rem)
+        st = jnp.where(winA, P.CS, st)
+        # the sleep->spin transition's payoff: a woken thread that finds the
+        # lock free acquired "slept and not spun" -> EvalSWS doubles the window
+        sws, cnt, ewma, wuc = oracle_acquire(jnp.any(winA, axis=-1), winA,
+                                             thc_of(st), sws, cnt, ewma, wuc)
+        losers = due & ~winA
+        to_spin = losers & (col(w2s_f) > 0)    # woken into the spinning window
+        st = jnp.where(to_spin, P.SPIN, st)
+        spun = jnp.where(to_spin, 1, spun)
+        # fissile (budget_spin + wake_to_spin) re-arms a fresh bounded budget;
+        # the mutable row's window spinners keep the unbounded inf sentinel
+        rem = jnp.where(to_spin,
+                        jnp.where(col(budget_f) > 0, budget_eff(sws), inf),
+                        rem)
+        to_park = losers & (col(repark_f) > 0)     # barged: park again
+        st, wake_at, permits, wake_count, slept, rem = park(
+            to_park, st, wake_at, permits, wake_count, slept, rem)
 
     # -- CS completion / release ------------------------------------------
-    holder_done = (st == P.CS) & (rem <= REM_EPS)
-    rel = jnp.any(holder_done, axis=-1)
-    completed = completed + rel.astype(jnp.int32)
-    completed_pt = completed_pt + holder_done.astype(jnp.int32)
-    thc_pre = thc_of(st)                                   # R14 (pre-FAD)
-    do_latch = rel & (win_f > 0)
-    r_wuc = jnp.where(do_latch & (wuc >= 0), wuc, -1)      # R2-R6
-    wuc = jnp.where(do_latch, jnp.where(wuc >= 0, 0, wuc + 1), wuc)  # R4/R7
-    ncs_val, ctr = draw_into(holder_done, ncs_lo, ncs_hi, ctr, is_ncs=1)
-    rem = jnp.where(holder_done, ncs_val, rem)
-    st = jnp.where(holder_done, P.NCS, st)                 # R9-R10
+    with jax.named_scope("release"):
+        holder_done = (st == P.CS) & (rem <= REM_EPS)
+        rel = jnp.any(holder_done, axis=-1)
+        completed = completed + rel.astype(jnp.int32)
+        completed_pt = completed_pt + holder_done.astype(jnp.int32)
+        thc_pre = thc_of(st)                                   # R14 (pre-FAD)
+        do_latch = rel & (win_f > 0)
+        r_wuc = jnp.where(do_latch & (wuc >= 0), wuc, -1)      # R2-R6
+        wuc = jnp.where(do_latch, jnp.where(wuc >= 0, 0, wuc + 1),
+                        wuc)                                   # R4/R7
+        ncs_val, ctr = draw_into(holder_done, ncs_lo, ncs_hi, ctr, is_ncs=1)
+        rem = jnp.where(holder_done, ncs_val, rem)
+        st = jnp.where(holder_done, P.NCS, st)                 # R9-R10
     # -- open-loop departure: an open config's completed request leaves
     # the system instead of drawing a fresh NCS — latency = now2 - req_t
     # lands in the log-spaced histogram and the SLO/latency counters; the
     # thread slot frees (DONE) for the end-of-step binding stage.
     if open_run:
-        depart = holder_done & openc
-        latv = col(now2) - req_t
-        binv = jnp.clip(
-            jnp.floor(jnp.log2(jnp.maximum(latv, jnp.float32(1e-30))
-                               / jnp.float32(P.LAT_BIN0))
-                      * jnp.float32(P.LAT_BINS_PER_OCTAVE)),
-            0, NB - 1).astype(jnp.int32)
-        has_dep = jnp.any(depart, axis=-1)
-        dep_bin = jnp.sum(jnp.where(depart, binv, 0), axis=-1)
-        nbi = jnp.arange(NB, dtype=jnp.int32)[None, :]
-        hist = hist + ((nbi == dep_bin[:, None]) & has_dep[:, None]
-                       ).astype(jnp.int32)
-        lat_sum = lat_sum + jnp.sum(jnp.where(depart, latv, 0.0), axis=-1)
-        departed = departed + has_dep.astype(jnp.int32)
-        slo_viol = slo_viol + jnp.sum(
-            (depart & (latv > col(slo))).astype(jnp.int32), axis=-1)
-        st = jnp.where(depart, P.DONE, st)
-        rem = jnp.where(depart, inf, rem)
-        req_t = jnp.where(depart, jnp.float32(-1.0), req_t)
+        with jax.named_scope("open_depart"):
+            depart = holder_done & openc
+            latv = col(now2) - req_t
+            binv = jnp.clip(
+                jnp.floor(jnp.log2(jnp.maximum(latv, jnp.float32(1e-30))
+                                   / jnp.float32(P.LAT_BIN0))
+                          * jnp.float32(P.LAT_BINS_PER_OCTAVE)),
+                0, NB - 1).astype(jnp.int32)
+            has_dep = jnp.any(depart, axis=-1)
+            dep_bin = jnp.sum(jnp.where(depart, binv, 0), axis=-1)
+            nbi = jnp.arange(NB, dtype=jnp.int32)[None, :]
+            hist = hist + ((nbi == dep_bin[:, None]) & has_dep[:, None]
+                           ).astype(jnp.int32)
+            lat_sum = lat_sum + jnp.sum(jnp.where(depart, latv, 0.0), axis=-1)
+            departed = departed + has_dep.astype(jnp.int32)
+            slo_viol = slo_viol + jnp.sum(
+                (depart & (latv > col(slo))).astype(jnp.int32), axis=-1)
+            st = jnp.where(depart, P.DONE, st)
+            rem = jnp.where(depart, inf, rem)
+            req_t = jnp.where(depart, jnp.float32(-1.0), req_t)
     # handoff: grant priority is the arrival ticket for FIFO rows, the
     # thread id otherwise — or, with tie_break="random", a fresh seeded
     # per-(thread, step) key (the DES picks a spinner at random; tb == 0
     # keeps the historical id order bit-identically, equal random keys
     # fall back to it)
-    spinners = st == P.SPIN
-    can_handoff = rel & (hand_f > 0) & jnp.any(spinners, axis=-1)
-    tb_u = counter_uniform(col(seed) ^ jnp.uint32(P.TB_SALT), tidb, stepuT)
-    rkey = (tb_u * jnp.float32(2 ** 23)).astype(jnp.int32)
-    key = jnp.where(spinners,
-                    jnp.where(col(fifo_f) > 0, ticket,
-                              jnp.where(col(tb) > 0, rkey, tidb)),
-                    NO_TICKET)
-    cand = spinners & (key == jnp.min(key, axis=-1, keepdims=True))
-    winB = first_oh(cand) & col(can_handoff)
-    cs_valB, ctr = draw_into(winB, cs_lo, cs_hi, ctr)
-    rem = jnp.where(winB, cs_valB, rem)
-    st = jnp.where(winB, P.CS, st)
-    sws, cnt, ewma, wuc = oracle_acquire(can_handoff, winB, thc_pre - 1,
-                                         sws, cnt, ewma, wuc)
+    with jax.named_scope("handoff"):
+        spinners = st == P.SPIN
+        can_handoff = rel & (hand_f > 0) & jnp.any(spinners, axis=-1)
+        tb_u = counter_uniform(col(seed) ^ jnp.uint32(P.TB_SALT), tidb, stepuT)
+        rkey = (tb_u * jnp.float32(2 ** 23)).astype(jnp.int32)
+        key = jnp.where(spinners,
+                        jnp.where(col(fifo_f) > 0, ticket,
+                                  jnp.where(col(tb) > 0, rkey, tidb)),
+                        NO_TICKET)
+        cand = spinners & (key == jnp.min(key, axis=-1, keepdims=True))
+        winB = first_oh(cand) & col(can_handoff)
+        cs_valB, ctr = draw_into(winB, cs_lo, cs_hi, ctr)
+        rem = jnp.where(winB, cs_valB, rem)
+        st = jnp.where(winB, P.CS, st)
+        sws, cnt, ewma, wuc = oracle_acquire(can_handoff, winB, thc_pre - 1,
+                                             sws, cnt, ewma, wuc)
     # wake quota: per-discipline rule (R11-R21 for the mutable row,
     # wake-one for sleep/adaptive, none for pure spin/FIFO)
-    n_parked = jnp.sum(((st == P.SLEEP_ST) | (st == P.WAKING))
-                       .astype(jnp.int32), axis=-1)
-    quota = P.discipline_release_quota(policy, r_wuc, thc_pre, sws,
-                                       n_parked,
-                                       can_handoff.astype(jnp.int32))
-    quota = jnp.where(rel, quota, 0)
-    sleepers = st == P.SLEEP_ST
-    rank_s = lane_cumsum(sleepers) - 1
-    sel_id = sleepers & (rank_s < col(quota))
-    # FIFO rows wake the oldest ticket first (hapax head-of-queue unlock;
-    # their quota is 0/1, so the single min-ticket pick covers it) — the
-    # never-parking fifo row has no sleepers, leaving sel_id untouched.
-    skey = jnp.where(sleepers, ticket, NO_TICKET)
-    sel_f = first_oh(sleepers
-                     & (skey == jnp.min(skey, axis=-1, keepdims=True))) \
-        & (col(quota) > 0)
-    sel = pick(col(fifo_f) > 0, sel_f, sel_id)
-    n_sel = jnp.sum(sel.astype(jnp.int32), axis=-1)
-    st = jnp.where(sel, P.WAKING, st)
-    wake_at = jnp.where(sel, col(now2) + wake_eff, wake_at)
-    wake_count = wake_count + n_sel
-    permits = permits + (quota - n_sel)    # park-free permits are banked
+    with jax.named_scope("wake_quota"):
+        n_parked = jnp.sum(((st == P.SLEEP_ST) | (st == P.WAKING))
+                           .astype(jnp.int32), axis=-1)
+        quota = P.discipline_release_quota(policy, r_wuc, thc_pre, sws,
+                                           n_parked,
+                                           can_handoff.astype(jnp.int32))
+        quota = jnp.where(rel, quota, 0)
+        sleepers = st == P.SLEEP_ST
+        rank_s = lane_cumsum(sleepers) - 1
+        sel_id = sleepers & (rank_s < col(quota))
+        # FIFO rows wake the oldest ticket first (hapax head-of-queue unlock;
+        # their quota is 0/1, so the single min-ticket pick covers it) — the
+        # never-parking fifo row has no sleepers, leaving sel_id untouched.
+        skey = jnp.where(sleepers, ticket, NO_TICKET)
+        sel_f = first_oh(sleepers
+                         & (skey == jnp.min(skey, axis=-1, keepdims=True))) \
+            & (col(quota) > 0)
+        sel = pick(col(fifo_f) > 0, sel_f, sel_id)
+        n_sel = jnp.sum(sel.astype(jnp.int32), axis=-1)
+        st = jnp.where(sel, P.WAKING, st)
+        wake_at = jnp.where(sel, col(now2) + wake_eff, wake_at)
+        wake_count = wake_count + n_sel
+        permits = permits + (quota - n_sel)    # park-free permits are banked
 
     # -- ttas_backoff polls (backoff rows only; exact no-op otherwise) ----
     # A handoff=0 release just frees the lock, so the poll IS the acquire
@@ -637,66 +650,71 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     # park, so ``wake_at`` doubles as the next-poll time and ``ticket`` as
     # the failed-attempt counter (both unread by the generic stages for
     # spinning threads).
-    bo_u = counter_uniform(col(seed) ^ jnp.uint32(P.BO_SALT), tidb, stepuT)
-    poll = (st == P.SPIN) & (col(backoff_f) > 0) \
-        & (wake_at <= col(now2 + teps))
-    holder_freeP = ~jnp.any(st == P.CS, axis=-1, keepdims=True)
-    winP = first_oh(poll) & holder_freeP
-    cs_valP, ctr = draw_into(winP, cs_lo, cs_hi, ctr)
-    rem = jnp.where(winP, cs_valP, rem)
-    st = jnp.where(winP, P.CS, st)
-    poll_fail = poll & ~winP
-    ticket = jnp.where(poll_fail, ticket + 1, ticket)
-    bo_exp = jnp.exp2(jnp.minimum(ticket, P.BO_CAP).astype(jnp.float32))
-    wake_at = jnp.where(poll_fail,
-                        col(now2) + col(spin_budget) * bo_exp * bo_u,
-                        wake_at)
+    with jax.named_scope("backoff_poll"):
+        bo_u = counter_uniform(col(seed) ^ jnp.uint32(P.BO_SALT), tidb, stepuT)
+        poll = (st == P.SPIN) & (col(backoff_f) > 0) \
+            & (wake_at <= col(now2 + teps))
+        holder_freeP = ~jnp.any(st == P.CS, axis=-1, keepdims=True)
+        winP = first_oh(poll) & holder_freeP
+        cs_valP, ctr = draw_into(winP, cs_lo, cs_hi, ctr)
+        rem = jnp.where(winP, cs_valP, rem)
+        st = jnp.where(winP, P.CS, st)
+        poll_fail = poll & ~winP
+        ticket = jnp.where(poll_fail, ticket + 1, ticket)
+        bo_exp = jnp.exp2(jnp.minimum(ticket, P.BO_CAP).astype(jnp.float32))
+        wake_at = jnp.where(poll_fail,
+                            col(now2) + col(spin_budget) * bo_exp * bo_u,
+                            wake_at)
 
     # -- arrivals (NCS finished) ------------------------------------------
-    arr = (st == P.NCS) & (rem <= REM_EPS) & active
-    thc_base = thc_of(st)
-    rank_a = lane_cumsum(arr) - 1
-    thc_pre_i = col(thc_base) + rank_a                     # A4 per arrival
-    slept = jnp.where(arr, 0, slept)                       # A3
-    spun = jnp.where(arr, 0, spun)
-    holder_free2 = ~jnp.any(st == P.CS, axis=-1, keepdims=True)
-    sleeps = arr & (P.discipline_arrival_sleeps(
-        col(policy), rank_a, thc_pre_i, col(sws),
-        holder_free2.astype(jnp.int32)) > 0)               # A7 per row
-    nonsleep = arr & ~sleeps
-    winC = first_oh(nonsleep) & holder_free2
-    cs_valC, ctr = draw_into(winC, cs_lo, cs_hi, ctr)
-    rem = jnp.where(winC, cs_valC, rem)
-    st = jnp.where(winC, P.CS, st)
-    sws, cnt, ewma, wuc = oracle_acquire(jnp.any(winC, axis=-1), winC,
-                                         thc_base + 1, sws, cnt, ewma, wuc)
-    to_spinC = nonsleep & ~winC
-    st = jnp.where(to_spinC, P.SPIN, st)
-    spun = jnp.where(to_spinC, 1, spun)
-    rem = jnp.where(to_spinC,
-                    jnp.where(col(budget_f) > 0, budget_eff(sws), inf),
-                    rem)
+    with jax.named_scope("arrivals"):
+        arr = (st == P.NCS) & (rem <= REM_EPS) & active
+        thc_base = thc_of(st)
+        rank_a = lane_cumsum(arr) - 1
+        thc_pre_i = col(thc_base) + rank_a                     # A4 per arrival
+        slept = jnp.where(arr, 0, slept)                       # A3
+        spun = jnp.where(arr, 0, spun)
+        holder_free2 = ~jnp.any(st == P.CS, axis=-1, keepdims=True)
+        sleeps = arr & (P.discipline_arrival_sleeps(
+            col(policy), rank_a, thc_pre_i, col(sws),
+            holder_free2.astype(jnp.int32)) > 0)               # A7 per row
+        nonsleep = arr & ~sleeps
+        winC = first_oh(nonsleep) & holder_free2
+        cs_valC, ctr = draw_into(winC, cs_lo, cs_hi, ctr)
+        rem = jnp.where(winC, cs_valC, rem)
+        st = jnp.where(winC, P.CS, st)
+        sws, cnt, ewma, wuc = oracle_acquire(jnp.any(winC, axis=-1), winC,
+                                             thc_base + 1, sws, cnt, ewma, wuc)
+        to_spinC = nonsleep & ~winC
+        st = jnp.where(to_spinC, P.SPIN, st)
+        spun = jnp.where(to_spinC, 1, spun)
+        rem = jnp.where(to_spinC,
+                        jnp.where(col(budget_f) > 0, budget_eff(sws), inf),
+                        rem)
     # ticket-order bookkeeping: every new waiter takes the next ticket
     # (rank order within the step); only FIFO rows read them for grants.
     # FIFO rows that park (hapax) ticket their parking arrivals too — for
     # every other row the joiner set is exactly the new spinners.
-    joiners = to_spinC | (sleeps & (col(fifo_f) > 0))
-    rank_t = lane_cumsum(joiners) - 1
-    ticket = jnp.where(joiners, col(nticket) + rank_t, ticket)
-    nticket = nticket + jnp.sum(joiners.astype(jnp.int32), axis=-1)
-    # backoff rows: a new spinner starts its attempt counter at 0 and
-    # schedules its first re-poll within one base delay
-    bo_new = to_spinC & (col(backoff_f) > 0)
-    ticket = jnp.where(bo_new, 0, ticket)
-    wake_at = jnp.where(bo_new, col(now2) + col(spin_budget) * bo_u,
-                        wake_at)
-    st, wake_at, permits, wake_count, slept, rem = park(
-        sleeps, st, wake_at, permits, wake_count, slept, rem)
+    with jax.named_scope("tickets"):
+        joiners = to_spinC | (sleeps & (col(fifo_f) > 0))
+        rank_t = lane_cumsum(joiners) - 1
+        ticket = jnp.where(joiners, col(nticket) + rank_t, ticket)
+        nticket = nticket + jnp.sum(joiners.astype(jnp.int32), axis=-1)
+        # backoff rows: a new spinner starts its attempt counter at 0 and
+        # schedules its first re-poll within one base delay
+        bo_new = to_spinC & (col(backoff_f) > 0)
+        ticket = jnp.where(bo_new, 0, ticket)
+        wake_at = jnp.where(bo_new, col(now2) + col(spin_budget) * bo_u,
+                            wake_at)
+    with jax.named_scope("arrivals"):
+        st, wake_at, permits, wake_count, slept, rem = park(
+            sleeps, st, wake_at, permits, wake_count, slept, rem)
     # retire tickets: spinners keep theirs; FIFO rows that park keep them
     # through SLEEP/WAKING so grants stay in arrival order
-    queued = (st == P.SPIN) | ((col(fifo_f) > 0)
-                               & ((st == P.SLEEP_ST) | (st == P.WAKING)))
-    ticket = jnp.where(queued, ticket, NO_TICKET)
+    with jax.named_scope("tickets"):
+        queued = (st == P.SPIN) | ((col(fifo_f) > 0)
+                                   & ((st == P.SLEEP_ST) | (st == P.WAKING)))
+        ticket = jnp.where(queued, ticket, NO_TICKET)
 
     if not open_run:
         return (st, rem, wake_at, slept, spun, ctr, ticket, completed_pt,
@@ -709,23 +727,24 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
     # integral accumulates LAST, so every in-system request (queued or
     # bound) is counted for exactly the steps between its admission and
     # its departure.
-    freem = active & (st == P.DONE) & openc
-    rank_f = lane_cumsum(freem) - 1
-    n_free = jnp.sum(freem.astype(jnp.int32), axis=-1)
-    n_bind = jnp.minimum(qlen, n_free)
-    bindm = freem & (rank_f < col(n_bind))
-    qpos = (col(qhead) + rank_f) % Q
-    rt = jnp.take_along_axis(qbuf, qpos, axis=1)
-    ncs_b, ctr = draw_into(bindm, ncs_lo, ncs_hi, ctr, is_ncs=1)
-    st = jnp.where(bindm, P.NCS, st)
-    rem = jnp.where(bindm, ncs_b, rem)
-    req_t = jnp.where(bindm, rt, req_t)
-    slept = jnp.where(bindm, 0, slept)
-    spun = jnp.where(bindm, 0, spun)
-    qhead = (qhead + n_bind) % Q
-    qlen = qlen - n_bind
-    busy = jnp.sum((active & (req_t >= 0.0)).astype(jnp.int32), axis=-1)
-    occ_int = occ_int + (qlen + busy).astype(jnp.float32) * dt
+    with jax.named_scope("open_bind"):
+        freem = active & (st == P.DONE) & openc
+        rank_f = lane_cumsum(freem) - 1
+        n_free = jnp.sum(freem.astype(jnp.int32), axis=-1)
+        n_bind = jnp.minimum(qlen, n_free)
+        bindm = freem & (rank_f < col(n_bind))
+        qpos = (col(qhead) + rank_f) % Q
+        rt = jnp.take_along_axis(qbuf, qpos, axis=1)
+        ncs_b, ctr = draw_into(bindm, ncs_lo, ncs_hi, ctr, is_ncs=1)
+        st = jnp.where(bindm, P.NCS, st)
+        rem = jnp.where(bindm, ncs_b, rem)
+        req_t = jnp.where(bindm, rt, req_t)
+        slept = jnp.where(bindm, 0, slept)
+        spun = jnp.where(bindm, 0, spun)
+        qhead = (qhead + n_bind) % Q
+        qlen = qlen - n_bind
+        busy = jnp.sum((active & (req_t >= 0.0)).astype(jnp.int32), axis=-1)
+        occ_int = occ_int + (qlen + busy).astype(jnp.float32) * dt
 
     return (st, rem, wake_at, slept, spun, ctr, ticket, completed_pt,
             sws, cnt, ewma, wuc, permits, nticket, completed, wake_count,
@@ -818,13 +837,14 @@ def lock_sim_block_ref(st, rem, wake_at, slept, spun, ctr, ticket,
         new, onew = out[:16], out[16:]
         if limit is None:
             return (*new, cpu + burn, *onew)
-        act = i < limit                       # bool scalar or (C,)
-        actT = act[..., None] if jnp.ndim(act) else act   # (C, 1) for (C, T)
-        state = tuple(jnp.where(actT if n.ndim == 2 else act, n, o)
-                      for n, o in zip(new, state))
-        ostate = tuple(jnp.where(actT if n.ndim == 2 else act, n, o)
-                       for n, o in zip(onew, ostate))
-        return (*state, cpu + jnp.where(act, burn, 0.0), *ostate)
+        with jax.named_scope("step_limit"):
+            act = i < limit                   # bool scalar or (C,)
+            actT = act[..., None] if jnp.ndim(act) else act  # (C, 1)
+            state = tuple(jnp.where(actT if n.ndim == 2 else act, n, o)
+                          for n, o in zip(new, state))
+            ostate = tuple(jnp.where(actT if n.ndim == 2 else act, n, o)
+                           for n, o in zip(onew, ostate))
+            return (*state, cpu + jnp.where(act, burn, 0.0), *ostate)
 
     carry = (st, rem, wake_at, slept, spun, ctr, ticket, completed_pt,
              sws, cnt, ewma, wuc, permits, nticket, completed, wake_count,

@@ -17,7 +17,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _LINK = re.compile(r"\[[^\]]*\]\(([^)\s]+)(?:\s+\"[^\"]*\")?\)")
 
 #: Modules that used to exist; docs must not point at them anymore.
-_DELETED = ("benchmarks/roofline.py", "benchmarks.roofline")
+_DELETED = ("benchmarks/roofline.py", "benchmarks.roofline",
+            "benchmarks/perf_bench.py", "benchmarks.perf_bench",
+            "BENCH_xdes.json")
 
 
 def _md_files():
