@@ -165,10 +165,10 @@ OPEN_STATE = ("req_t", "qbuf", "hist", "qhead", "qlen", "arrived", "shed",
 # --------------------------------------------------------------------------
 # Mosaic-compilable spellings of three primitives the TPU kernel lowering
 # refuses (uint32 -> float32 casts, lane-axis cumsum, argmax of a bool
-# mask), each exactly equal to the primitive it replaces, and a product
-# that rounds the same under every compiler's fusion.  So the XLA
-# reference and the compiled kernel keep every simulated bit (pinned by
-# tests/test_lock_kernel_ops.py).
+# mask), each exactly equal to the primitive it replaces, a gather-free
+# ring read, and a product that rounds the same under every compiler's
+# fusion.  So the XLA reference and the compiled kernel keep every
+# simulated bit (pinned by tests/test_lock_kernel_ops.py).
 # --------------------------------------------------------------------------
 def u32_to_f32(x):
     """``x.astype(jnp.float32)`` for a uint32 ``x``, bit for bit.
@@ -202,6 +202,23 @@ def first_true(mask):
     T = mask.shape[-1]
     idx = jax.lax.broadcasted_iota(jnp.int32, mask.shape, mask.ndim - 1)
     return jnp.min(jnp.where(mask, idx, T), axis=-1, keepdims=True)
+
+
+def ring_take(qbuf, qpos):
+    """``jnp.take_along_axis(qbuf, qpos, axis=1)`` of a ``(C, Q)`` f32 ring
+    at ``(C, T)`` slots ``0 <= qpos < Q``, bit for bit.
+
+    The TPU lowers a per-lane gather to a slow indexed path; this is a
+    compare-and-select over the Q slots reduced by max.  Exactly one slot
+    matches and every other reads -inf, so the max is that slot's value
+    (-0.0 and +-inf included) in any reduction order.  Configs run along
+    the lanes and slots along the leading axis, so the max is elementwise
+    over whole vector registers, not a cross-lane reduce per (config,
+    thread)."""
+    Q = qbuf.shape[1]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (Q, 1, 1), 0)
+    hit = qpos.T[None, :, :] == slot
+    return jnp.max(jnp.where(hit, qbuf.T[:, None, :], -jnp.inf), axis=0).T
 
 
 def count_scale(x, n):
@@ -734,7 +751,7 @@ def lock_transitions_ref(st, rem, wake_at, slept, spun, ctr, ticket,
         n_bind = jnp.minimum(qlen, n_free)
         bindm = freem & (rank_f < col(n_bind))
         qpos = (col(qhead) + rank_f) % Q
-        rt = jnp.take_along_axis(qbuf, qpos, axis=1)
+        rt = ring_take(qbuf, qpos)
         ncs_b, ctr = draw_into(bindm, ncs_lo, ncs_hi, ctr, is_ncs=1)
         st = jnp.where(bindm, P.NCS, st)
         rem = jnp.where(bindm, ncs_b, rem)

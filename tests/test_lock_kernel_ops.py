@@ -1,12 +1,13 @@
 """The Mosaic-compilable spellings in :mod:`repro.kernels.ref` are exactly
 the jnp primitives they replace (uint32 -> float32, lane cumsum, first
-True), so the fused kernel keeps every simulated bit."""
+True, the ring read), so the fused kernel keeps every simulated bit."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.ref import count_scale, first_true, lane_cumsum, u32_to_f32
+from repro.kernels.ref import (count_scale, first_true, lane_cumsum,
+                               ring_take, u32_to_f32)
 
 EDGES = np.array([0, 1, 2**16 - 1, 2**16, 2**24 - 1, 2**24, 2**24 + 1,
                   2**24 + 3, 2**25 + 2, 2**31 - 1, 2**31, 0xFFFFFF7F,
@@ -65,6 +66,27 @@ def test_first_true_matches_argmax(T):
     has = mask.any(axis=-1)
     np.testing.assert_array_equal(got[has], np.argmax(mask, axis=-1)[has])
     assert (got[~has] == T).all()
+
+
+@pytest.mark.parametrize("T", [1, 7, 32, 128])
+def test_ring_take_matches_take_along_axis(T):
+    """Every ring head 0..127 (so every wrap-around), slots as the open
+    bind forms them, rings holding -0.0, repeats and +-inf."""
+    Q = 128
+    rng = np.random.default_rng(T)
+    qbuf = rng.standard_normal((Q, Q)).astype(np.float32)
+    qbuf[:, ::5] = -0.0
+    qbuf[:, 1::7] = 0.0
+    qbuf[:, 2::11] = np.inf
+    qbuf[:, 3::13] = -np.inf
+    qbuf[:, 4::3] = qbuf[:, 4:5]               # repeated values
+    qhead = np.arange(Q, dtype=np.int32)[:, None]
+    rank = np.sort(rng.integers(-1, T, (Q, T)), axis=1).astype(np.int32)
+    qpos = jnp.asarray((qhead + rank) % Q)
+    got = ring_take(jnp.asarray(qbuf), qpos)
+    want = jnp.take_along_axis(jnp.asarray(qbuf), qpos, axis=1)
+    assert got.dtype == want.dtype and got.shape == (Q, T)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
 def test_count_scale_rounds_once_even_when_fused():

@@ -252,6 +252,30 @@ def test_open_ref_vs_pallas_blocked(block_steps):
     _assert_open_equal(ref, scan, f"blocked==scan B={block_steps}")
 
 
+@pytest.mark.parametrize("block_steps", [1, 32])
+def test_open_bind_ring_take_matches_gather(block_steps, monkeypatch):
+    """The whole open-loop stage with the compare-and-select ring read
+    equals the stage with the ``take_along_axis`` gather it replaced."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ref as R
+
+    cfgs = _parity_batch()
+    run = lambda: xdes.simulate_batch(cfgs, n_steps=260, rollout="blocked",
+                                      block_steps=block_steps, backend="ref")
+    new = run()
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(R, "ring_take",
+                      lambda x, i: jnp.take_along_axis(x, i, axis=1))
+            jax.clear_caches()              # retrace with the gather
+            old = run()
+    finally:
+        jax.clear_caches()
+    _assert_open_equal(new, old, f"ring_take==gather B={block_steps}")
+
+
 def test_latency_percentile_determinism():
     """Same seed => identical on-device histograms and identical
     p50/p95/p99, across separate calls (the CI determinism check)."""
