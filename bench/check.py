@@ -3,13 +3,15 @@ plain reference, and the program's counts against the guarantees its
 configuration states.
 
 * ``sample`` configurations are drawn from the seed out of every sweep
-  of the window, plus the one that simulated the longest time. The reference simulator
-  (:mod:`bench.reference.lockdes`, event by event on the host) runs each
-  to ``reference_cs`` critical sections; an open-loop configuration runs
-  for as long as the program simulated it, its ``steps_run`` times the
-  time step its configuration states (the reference checks the
-  program's answer for the length the program served, as a served
-  model's reference scores the tokens the model served).
+  of the window, plus the one that simulated the longest time. The
+  configuration's reference (``reference/<name>.py``, its ``run_spec``;
+  :mod:`bench.reference.lockdes`, event by event on the host, where the
+  configuration names none) runs each to ``reference_cs`` critical
+  sections; an open-loop configuration runs for as long as the program
+  simulated it, its ``steps_run`` times the time step its configuration
+  states (the reference checks the program's answer for the length the
+  program served, as a served model's reference scores the tokens the
+  model served).
 * For each quantity -- ``thr`` (completed critical sections per
   simulated second), ``spin`` (spin CPU as a share of the machine's CPU
   time), ``wake`` (wake-ups per critical section) and, on open-loop rows,
@@ -44,6 +46,8 @@ import multiprocessing as mp
 from collections import defaultdict
 
 import numpy as np
+
+from bench.harness import ROOT, load_module
 
 #: Values the reference takes for RAW columns a generator leaves out (the
 #: program's defaults for them).
@@ -92,10 +96,13 @@ def reference_spec(sw: dict, res, i: int, seed: int, dt) -> dict:
     with its own simulation seed. An open-loop row also takes the length
     the program simulated, its step count times the stated time step
     ``dt``: its queue, and so its latency, depends on how long arrivals
-    ran."""
+    ran. Columns the sweep names under ``reference_cols`` come by name
+    too, one value per row: a vector row as a list."""
     cols = sw["cols"]
     spec = {c: (cols[c][i].item() if c in cols else _DEFAULTS[c])
             for c in _SPEC_COLS}
+    spec.update({c: np.asarray(cols[c])[i].tolist()
+                 for c in sw.get("reference_cols", ())})
     spec.update({k: str(v[i]) for k, v in sw["names"].items()})
     spec["alpha"] = float(sw["alpha"][i])
     spec["seed"] = int(seed)
@@ -122,19 +129,19 @@ def sample_rows(sweeps: list, n: int, seed: int) -> list:
 
 
 def _run_one(args):
-    from bench.reference import lockdes
-    spec, target = args
-    return lockdes.run_spec(spec, target)
+    root, reference, spec, target = args
+    return load_module("reference", reference, root).run_spec(spec, target)
 
 
-def run_reference(specs: list[dict], target_cs: int,
-                  workers: int) -> list[dict]:
-    """The reference over every spec, in ``workers`` fresh processes that
-    import nothing of the program and never touch the device."""
+def run_reference(specs: list[dict], target_cs: int, workers: int,
+                  reference: str, root: str) -> list[dict]:
+    """The reference ``reference/<reference>.py`` under ``root`` over
+    every spec, in ``workers`` fresh processes that import nothing of the
+    program and never touch the device."""
     ctx = mp.get_context("spawn")
     with ctx.Pool(workers) as pool:
-        return pool.map(_run_one, [(s, target_cs) for s in specs],
-                        chunksize=1)
+        return pool.map(_run_one, [(root, reference, s, target_cs)
+                                   for s in specs], chunksize=1)
 
 
 def bin_mid(latency: float) -> float:
@@ -305,11 +312,13 @@ def verdict(numbers: dict, limits: dict) -> tuple[bool, dict]:
 
 
 def compare(sweeps: list, seed: int, params: dict,
-            rows: list | None = None) -> dict:
-    """All numbers of a run: the sampled reference comparison, the exact
-    checks over every configuration and, where the cell reduces on
-    device, the win tables of every sweep. ``rows``, where given, gets
-    each sampled row's readings of both sides."""
+            rows: list | None = None, *, reference: str = "lockdes",
+            root: str = ROOT) -> dict:
+    """All numbers of a run: the sampled comparison with the reference
+    ``reference/<reference>.py`` under ``root``, the exact checks over
+    every configuration and, where the cell reduces on device, the win
+    tables of every sweep. ``rows``, where given, gets each sampled row's
+    readings of both sides."""
     pairs = sample_rows(sweeps, int(params["sample"]), seed)
     dts = [stated_dt(s["sw"]["cols"]) for s in sweeps]
     rng = np.random.default_rng([int(seed), 0x5EED])
@@ -317,7 +326,7 @@ def compare(sweeps: list, seed: int, params: dict,
     specs = [reference_spec(sweeps[j]["sw"], sweeps[j]["res"], i, s, dts[j])
              for (j, i), s in zip(pairs, ref_seeds)]
     ref = run_reference(specs, int(params["reference_cs"]),
-                        int(params["workers"]))
+                        int(params["workers"]), reference, root)
     prog = [program_row(sweeps[j]["sw"], sweeps[j]["res"], i)
             for j, i in pairs]
     refr = [reference_row(r, s) for r, s in zip(ref, specs)]

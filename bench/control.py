@@ -46,10 +46,9 @@ def main(argv=None) -> int:
     harness.check_devices(int(cell["cell"]["chips"]))
     tc = int(cfg["target_cs"])
 
-    def control_sweep(cols, *, target_cs, max_threads, reduce=None):
+    def control_sweep(cols, *, target_cs, **kw):
         dt = entry.planned_dt(cols, target_cs) * args.dt_scale
-        return entry.run_sweep(cols, target_cs=target_cs,
-                               max_threads=max_threads, reduce=reduce, dt=dt)
+        return entry.run_sweep(cols, target_cs=target_cs, dt=dt, **kw)
 
     out = open(args.out, "w") if args.out else None
     plan = ([(int(s), "sound", entry.run_sweep)
@@ -62,7 +61,9 @@ def main(argv=None) -> int:
             sweeps = [harness.one_sweep(gen, cfg, traffic, seed, k, fn)
                       for k in range(args.sweeps)]
             rows: list = []
-            numbers = check.compare(sweeps, seed, cell["limits"], rows)
+            numbers = check.compare(sweeps, seed, cell["limits"], rows,
+                                    reference=cell["reference"],
+                                    root=cell["root"])
         row = {"workload": args.workload, "seed": seed, "mode": mode,
                "dt_scale": args.dt_scale if mode == "control" else 1.0,
                "sweeps": args.sweeps, "target_cs": tc,

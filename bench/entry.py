@@ -40,12 +40,14 @@ def planned_dt(cols: dict, target_cs: int):
 
 
 def run_sweep(cols: dict, *, target_cs: int, max_threads: int,
-              reduce: dict | None = None, dt=None):
+              reduce: dict | None = None, dt=None, **program):
     """The entry the window drives: ``sweep_stream`` over RAW columns at
     the program's default backend, sharding, chunking and memory budget.
     ``reduce`` (``group``, ``cell_ids``, ``n_cells``) asks for the
     on-device win-count table. ``dt`` overrides the planner's time step,
-    which only the control of the correctness check does."""
+    which only the control of the correctness check does. ``program``
+    holds the static keyword arguments a generator's sweep names, passed
+    to ``sweep_stream`` as they are."""
     red = None if reduce is None else CellReduce(**reduce)
     return sweep_stream(cols, target_cs=target_cs, max_threads=max_threads,
-                        reduce=red, dt=dt)
+                        reduce=red, dt=dt, **program)

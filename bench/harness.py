@@ -3,16 +3,21 @@ reduction, the correctness check, and the result line.
 
 Everything that belongs to a cell is found by name from
 ``BENCHMARK.json``: the configuration file (``configs/<config>.json``,
-which names its generator in ``generators/``), the traffic mix
-(``traffic/<traffic>.json``), the check's limits (``limits/<cell>.json``)
-and one reader per per-layer metric (``metrics/<metric>.py``). Adding a
-cell or a metric adds files and entries; this file does not change.
+which names its generator in ``generators/`` and, under ``reference``,
+its plain reference in ``reference/``, ``lockdes`` where it names none),
+the traffic mix (``traffic/<traffic>.json``), the check's limits
+(``limits/<cell>.json``) and one reader per per-layer metric
+(``metrics/<metric>.py``). Generators, references and readers are loaded
+from their paths under the checkout's root. A generator's sweep may hand
+the program static keyword arguments (``program``) and the reference
+further columns (``reference_cols``). Adding a cell, a configuration with
+its own generator and reference, or a metric adds files and entries; this
+file does not change.
 """
 
 from __future__ import annotations
 
 import contextlib
-import importlib
 import importlib.util
 import json
 import os
@@ -33,6 +38,28 @@ def _json(path: str) -> dict:
         return json.load(f)
 
 
+def load_module(kind: str, name: str, root: str = ROOT):
+    """``bench/<kind>/<name>.py`` under ``root``, loaded from its path as
+    module ``bench.<kind>.<name>``, kept in ``sys.modules`` (dataclasses
+    look their module up there); a module already loaded from that path is
+    reused."""
+    path = os.path.join(root, "bench", kind, name + ".py")
+    modname = f"bench.{kind}.{name.replace('.', '_')}"
+    mod = sys.modules.get(modname)
+    if mod is not None and os.path.realpath(
+            getattr(mod, "__file__", None) or "") == os.path.realpath(path):
+        return mod
+    spec = importlib.util.spec_from_file_location(modname, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[modname] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[modname]
+        raise
+    return mod
+
+
 def load_cell(name: str, root: str = ROOT) -> dict:
     """Resolve a cell of ``BENCHMARK.json`` to its files and metrics."""
     bench = _json(os.path.join(root, "BENCHMARK.json"))
@@ -49,12 +76,13 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     return {
         "cell": cell,
         "config": config,
+        "root": root,
+        "reference": config.get("reference", "lockdes"),
         "traffic": _json(os.path.join(root, "bench", "traffic",
                                       cell["traffic"] + ".json")),
         "limits": _json(os.path.join(root, "bench", "limits",
                                      name + ".json")),
-        "generator": importlib.import_module(
-            "bench.generators." + config["generator"]),
+        "generator": load_module("generators", config["generator"], root),
         "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
         "per_layer": [m for m in bench["per_layer"] if mine(m)],
     }
@@ -62,12 +90,7 @@ def load_cell(name: str, root: str = ROOT) -> dict:
 
 def metric_reader(name: str):
     """The ``read(record)`` function of ``metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench.metrics." + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", name).read
 
 
 def check_devices(chips: int):
@@ -111,7 +134,8 @@ class CompileCounter:
 
 
 def one_sweep(gen, cfg: dict, traffic: dict, seed: int, k: int, run_sweep):
-    """Spec to diagram for sweep ``k``, each stage under its host span."""
+    """Spec to diagram for sweep ``k``, each stage under its host span.
+    The program gets the sweep's ``program`` keywords, where it has any."""
     from jax.profiler import TraceAnnotation
 
     with TraceAnnotation("bench.spec"):
@@ -119,7 +143,7 @@ def one_sweep(gen, cfg: dict, traffic: dict, seed: int, k: int, run_sweep):
     with TraceAnnotation("bench.sweep_stream"):
         res = run_sweep(sw["cols"], target_cs=int(cfg["target_cs"]),
                         max_threads=int(traffic["max_threads"]),
-                        reduce=sw["reduce"])
+                        reduce=sw["reduce"], **sw.get("program", {}))
     with TraceAnnotation("bench.diagram"):
         diagram = gen.diagram(sw, res)
     return {"sw": sw, "res": res, "diagram": diagram}
@@ -187,6 +211,22 @@ def configs_per_s(win: dict) -> float:
         / win["window_s"]
 
 
+def print_setup(t_devs: float, setup_s: float) -> None:
+    """Where set-up went, on standard error: start to devices ready, the
+    warm-up sweep, and of it the program's compile counters (tracing,
+    lowering, backend compile or cache load; the persistent cache's hits
+    and misses), where the program has them."""
+    from bench import entry
+
+    module = importlib.import_module(entry.enable_compile_cache.__module__)
+    counter = getattr(module, "compile_seconds", None)
+    c = counter() if counter else {}
+    print(f"set-up {setup_s:.2f} s: devices ready at {t_devs:.2f} s, "
+          f"warm-up sweep {setup_s - t_devs:.2f} s; compile "
+          + ", ".join(f"{k} {v:.2f}" if isinstance(v, float) else f"{k} {v}"
+                      for k, v in c.items()), file=sys.stderr)
+
+
 def run(args, t0: float, *, require_chip: bool = True, run_sweep=None,
         cell: dict | None = None) -> dict:
     """One run of ``args.workload``; returns the result line's object.
@@ -204,8 +244,10 @@ def run(args, t0: float, *, require_chip: bool = True, run_sweep=None,
             else jax.devices())
     run_sweep = run_sweep or entry.run_sweep
 
+    t_devs = time.monotonic() - t0
     one_sweep(gen, cfg, traffic, args.seed, WARMUP_K, run_sweep)
     setup_s = time.monotonic() - t0
+    print_setup(t_devs, setup_s)
 
     counter = CompileCounter()
     tracer = Tracer() if args.trace else None
@@ -247,7 +289,8 @@ def run(args, t0: float, *, require_chip: bool = True, run_sweep=None,
 
     lim = cell["limits"]
     t_check = time.monotonic()
-    numbers = check.compare(win["sweeps"], args.seed, lim)
+    numbers = check.compare(win["sweeps"], args.seed, lim,
+                            reference=cell["reference"], root=cell["root"])
     print(f"reference check took {time.monotonic() - t_check:.1f} s",
           file=sys.stderr)
     ok, table = check.verdict(numbers, lim["limits"])

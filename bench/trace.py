@@ -28,6 +28,12 @@ from collections import defaultdict
 OPS_LINE = "XLA Ops"
 #: Operations that only run other operations.
 CONTROL_OPS = ("while", "conditional", "call")
+#: XLA's opcodes that exchange data between chips; an opcode that starts
+#: with one of them (``all-reduce-start``, ``all-gather-done``) is one too.
+#: An operation's name does not say it: a ``psum`` in ``shard_map``
+#: compiles to ``%psum.13 = s32[] all-reduce(...)``.
+COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
+                  "collective-permute", "all-to-all")
 #: The benchmark's host spans, in the order a sweep passes through them.
 SPANS = ("bench.spec", "bench.sweep_stream", "bench.diagram")
 
@@ -58,12 +64,39 @@ def op_name(hlo: str) -> str:
     return hlo.split(" = ", 1)[0].lstrip("%")
 
 
+def op_head(hlo: str) -> str:
+    """An operation's HLO text up to its operands: ``%psum.13 = s32[]
+    all-reduce(%x), channel_id=1, ...`` gives ``%psum.13 = s32[]
+    all-reduce``. Text without `` = `` is a bare name and stays as it is."""
+    name, eq, rhs = hlo.partition(" = ")
+    if not eq:
+        return hlo
+    depth = 0
+    for k, ch in enumerate(rhs):     # the shape, a tuple's in parentheses
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == " " and depth == 0:
+            return f"{name} = {rhs[:k]} {rhs[k + 1:].split('(', 1)[0]}"
+    return hlo
+
+
+def op_code(head: str) -> str:
+    """The opcode of an :func:`op_head` (its last word); ``""`` for a bare
+    name, which says none."""
+    _, eq, rhs = head.partition(" = ")
+    return rhs.rsplit(" ", 1)[-1] if eq else ""
+
+
 def leaves(ops) -> tuple[list, list]:
     """``(leaf, enclosing)`` operations of one line: an enclosing one is a
     control-flow operation by name, or any event that another event of
     the line lies inside. Operations of a line run one at a time, except
     where one encloses others."""
-    control = [op[2].split(".")[0] in CONTROL_OPS for op in ops]
+    kinds = {n: op_name(n).split(".")[0] in CONTROL_OPS
+             for n in {op[2] for op in ops}}
+    control = [kinds[op[2]] for op in ops]
     # of two events with one interval, a control-flow one is the outer
     order = sorted(range(len(ops)),
                    key=lambda k: (ops[k][0], -ops[k][1], not control[k]))
@@ -82,8 +115,9 @@ def leaves(ops) -> tuple[list, list]:
 
 
 def read(data) -> tuple[dict, list]:
-    """``({device: [(start_ns, end_ns, op_name), ...]}, [(start_ns,
-    end_ns, span_name), ...])``: device operations per chip, and the
+    """``({device: [(start_ns, end_ns, op_head), ...]}, [(start_ns,
+    end_ns, span_name), ...])``: device operations per chip, each by its
+    :func:`op_head` (one string per distinct instruction), and the
     benchmark's host spans, from a ``jax.profiler.ProfileData`` or the
     serialized trace a profiler session returns."""
     if isinstance(data, bytes):
@@ -92,12 +126,20 @@ def read(data) -> tuple[dict, list]:
         data = ProfileData.from_serialized_xspace(data)
     devices: dict = {}
     spans: list = []
+    heads: dict = {}
+
+    def head(hlo):
+        h = heads.get(hlo)
+        if h is None:
+            h = heads[hlo] = op_head(hlo)
+        return h
+
     for plane in data.planes:
         if plane.name.startswith("/device:"):
             lines = [ln for ln in plane.lines if ln.name == OPS_LINE]
             if lines:        # a chip; planes without operations are not
                 devices[plane.name] = [
-                    (ev.start_ns, ev.end_ns, op_name(ev.name))
+                    (ev.start_ns, ev.end_ns, head(ev.name))
                     for ln in lines for ev in ln.events]
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -115,7 +157,11 @@ def summarize(devices: dict, spans: list, top: int = 10) -> dict:
     gaps are named by the host span in progress at the gap's middle and
     by whether an enclosing operation (a device loop) spans it, and
     ``any_op_ns`` is the time in which any operation, an enclosing one
-    included, was in progress."""
+    included, was in progress. ``collective_ns_total`` is the time of the
+    leaf operations whose opcode exchanges data between chips
+    (:data:`COLLECTIVE_OPS`), summed over the devices as
+    ``busy_ns_total`` is. An operation is named by its :func:`op_head` or
+    a bare name; ``device_ops`` names it by :func:`op_name`."""
     if not spans:
         raise ValueError("no benchmark spans in the trace")
     lo = min(s for s, _, _ in spans)
@@ -145,6 +191,9 @@ def summarize(devices: dict, spans: list, top: int = 10) -> dict:
                     where += ", inside a device loop"
                 gaps.append((b - a, where))
     n_dev = max(len(busy), 1)
+    by_name: dict = defaultdict(int)
+    for n, t in op_time.items():
+        by_name[op_name(n)] += t
     by_span: dict = defaultdict(int)
     for d, n in gaps:
         by_span[n] += d
@@ -154,7 +203,10 @@ def summarize(devices: dict, spans: list, top: int = 10) -> dict:
         "busy_ns": sum(busy) / n_dev,
         "busy_ns_total": sum(busy),
         "any_op_ns": sum(any_op) / n_dev,
-        "device_ops": sorted(op_time.items(), key=lambda kv: -kv[1])[:top],
+        "collective_ns_total": sum(
+            t for n, t in op_time.items()
+            if op_code(n).startswith(COLLECTIVE_OPS)),
+        "device_ops": sorted(by_name.items(), key=lambda kv: -kv[1])[:top],
         "idle_gaps": sorted(((n, d / n_dev) for n, d in by_span.items()),
                             key=lambda kv: -kv[1])[:top],
     }
